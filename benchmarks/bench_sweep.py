@@ -300,6 +300,12 @@ def _time_mc_solver(repeat=3):
 _SHARDED_GATE_CAP = 2.0
 
 
+def _cpu_child_env(**extra) -> dict:
+    """Environment of a bench subprocess: pinned to the CPU backend, so a
+    child never asks for the accelerator its parent process holds."""
+    return dict(os.environ, JAX_PLATFORMS="cpu", **extra)
+
+
 #: virtual devices for the sharded bench subprocess: one per core, capped
 #: at the acceptance target's 8 (oversubscribing cores with more virtual
 #: devices than hardware threads just measures scheduler noise).
@@ -377,7 +383,8 @@ def _time_sharded_dense():
     ndev = _bench_device_count()
     script = _SHARDED_SCRIPT % {"ndev": ndev, "src": str(ROOT / "src")}
     out = subprocess.run([sys.executable, "-c", script],
-                         capture_output=True, text=True, timeout=1200)
+                         capture_output=True, text=True, timeout=1200,
+                         env=_cpu_child_env())
     if out.returncode != 0:
         raise RuntimeError(f"sharded bench subprocess failed:\n"
                            f"{out.stderr[-3000:]}")
@@ -433,8 +440,6 @@ _COLD_START_SCRIPT = r"""
 import os, sys, time
 sys.path.insert(0, r"%(src)s")
 import numpy as np
-from repro.sim import enable_compile_cache
-enable_compile_cache(r"%(cache)s")
 from repro.sim import mu_rho_grid, evaluate_grid, ParamGrid, \
     simulate_trajectories
 from repro.core import fig12_checkpoint, EXASCALE_POWER_RHO55
@@ -521,10 +526,13 @@ def _time_cold_start_cached():
     inside the subprocesses (jax import time excluded).
     """
     def one(cache_dir):
-        script = _COLD_START_SCRIPT % {"src": str(ROOT / "src"),
-                                       "cache": cache_dir}
+        script = _COLD_START_SCRIPT % {"src": str(ROOT / "src")}
+        # Cache every program, however fast it compiles on the CPU.
+        env = _cpu_child_env(JAX_COMPILATION_CACHE_DIR=cache_dir,
+                             JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0")
         out = subprocess.run([sys.executable, "-c", script],
-                             capture_output=True, text=True, timeout=1200)
+                             capture_output=True, text=True, timeout=1200,
+                             env=env)
         if out.returncode != 0:
             raise RuntimeError(f"cold-start subprocess failed:\n"
                                f"{out.stderr[-3000:]}")

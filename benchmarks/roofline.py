@@ -24,9 +24,9 @@ Two sections, one loop-aware HLO cost model (``repro.launch.hlo_cost``):
    position is memory-side by construction; the table publishes the
    HBM traffic and arithmetic-intensity ceiling that implies.
 
-Peaks come from the per-backend ``PEAKS`` table (keyed by device kind /
-platform) and every emitted CSV/markdown records which peaks produced
-it; override any of them with ``--peak-flops / --hbm-bw / --link-bw``
+Peaks come from the ``PEAKS`` table, keyed by the device kind JAX
+reports (a kind that is not in the table is an error, never a default),
+and every emitted CSV/markdown records which peaks produced it; override any of them with ``--peak-flops / --hbm-bw / --link-bw``
 (plain floats, e.g. ``--peak-flops 312e12`` for an A100 bf16 TC run).
 """
 from __future__ import annotations
@@ -57,15 +57,12 @@ class Peaks:
                      link_bw or self.link_bw, self.source + " + cli override")
 
 
-#: per-backend peak table.  Keys are matched (case-insensitively) against
-#: the device KIND first (longest match wins — "tpu v4" beats "tpu"),
-#: then the platform name.  Sources are deliberately coarse public
-#: datasheet numbers: the roofline separates decades, not percent.
+#: peak table, keyed by ``device_kind`` in lower case ("TPU v5 lite" is
+#: how JAX names a v5e chip).  Sources are public datasheet numbers.
 PEAKS = {
     "tpu v4": Peaks(275e12, 1228e9, 50e9, "TPU v4 datasheet (bf16)"),
-    "tpu v5 lite": Peaks(197e12, 819e9, 50e9, "TPU v5e datasheet (bf16)"),
-    "tpu": Peaks(197e12, 819e9, 50e9, "TPU default = v5e class (bf16)"),
-    "gpu": Peaks(19.5e12, 1555e9, 300e9, "A100-40GB class (f32 non-TC)"),
+    "tpu v5 lite": Peaks(197e12, 819e9, 200e9,
+                         "Google Cloud 'TPU v5e' (bf16; 1,600 Gbit/s ICI)"),
     "cpu": Peaks(5e10, 2e10, 1e10,
                  "order-of-magnitude host estimate "
                  "(per-core f64 FMA / DDR stream share)"),
@@ -73,30 +70,27 @@ PEAKS = {
 
 #: the launch-plan section models the TPU fleet the plans target,
 #: whatever host runs the analysis.
-PLAN_BACKEND = "tpu"
+PLAN_BACKEND = "TPU v5 lite"
 
 
-def resolve_peaks(device_kind: str = "", platform: str = "",
-                  peak_flops=None, hbm_bw=None, link_bw=None) -> Peaks:
-    """Pick the peak entry for a backend, longest device-kind key first,
-    then platform, then the cpu floor; apply any CLI overrides."""
-    kind = (device_kind or "").lower()
-    hits = [k for k in PEAKS if k in kind]
-    if hits:
-        key = max(hits, key=len)
-    elif (platform or "").lower() in PEAKS:
-        key = platform.lower()
-    else:
-        key = "cpu"
-    return PEAKS[key].replaced(peak_flops, hbm_bw, link_bw)
+def resolve_peaks(device_kind: str, peak_flops=None, hbm_bw=None,
+                  link_bw=None) -> Peaks:
+    """The peak entry for ``device_kind`` (raises on a kind not in the
+    table); apply any CLI overrides."""
+    try:
+        peaks = PEAKS[device_kind.lower()]
+    except KeyError:
+        raise ValueError(f"no peaks for device kind {device_kind!r}; "
+                         f"add it to PEAKS with its source") from None
+    return peaks.replaced(peak_flops, hbm_bw, link_bw)
 
 
 def host_peaks(peak_flops=None, hbm_bw=None, link_bw=None):
     """Peaks for THIS process's jax backend (the sweep-engine section)."""
     from repro.sim import backend_info
     info = backend_info()
-    return info, resolve_peaks(info.device_kind, info.platform,
-                               peak_flops, hbm_bw, link_bw)
+    return info, resolve_peaks(info.device_kind, peak_flops, hbm_bw,
+                               link_bw)
 
 
 # ---------------------------------------------------------------------------
@@ -348,8 +342,7 @@ def main(argv=None):
     args = ap.parse_args(argv)
     over = (args.peak_flops, args.hbm_bw, args.link_bw)
 
-    plan_peaks = resolve_peaks(platform=PLAN_BACKEND, peak_flops=over[0],
-                               hbm_bw=over[1], link_bw=over[2])
+    plan_peaks = resolve_peaks(PLAN_BACKEND, *over)
     (out, rows), us = timed(lambda: run(plan_peaks), repeat=1)
     info, hpeaks = host_peaks(*over)
     (sout, srows), sus = timed(

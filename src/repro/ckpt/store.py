@@ -27,7 +27,8 @@ manager's flush controller consults the same plan at its own points
 Optional int8 blockwise compression (``compress=True``) uses the
 ``quant_blockwise`` kernel — ~4x smaller payloads for f32 state, directly
 shrinking the paper's C parameter (lossy: bounded by absmax/127 per block;
-applied to every leaf EXCEPT ones whose path matches ``no_compress``).
+applied to every f32 leaf EXCEPT ones with a name from
+``no_compress_paths`` on their tree path).
 """
 from __future__ import annotations
 
@@ -143,8 +144,11 @@ class StoreConfig:
     root: str
     retain: int = 2
     compress: bool = False
-    # leaf indices are compared against this predicate via their tree path
-    no_compress_paths: tuple = ("step",)
+    #: leaves with one of these names on their tree path are stored exact.
+    #: AdamW's second moment ``v`` is one: its update divides by
+    #: ``sqrt(v)``, so an entry that rounds to zero in its int8 block
+    #: blows the next step up.
+    no_compress_paths: tuple = ("step", "v")
 
 
 class ShardedStore:
@@ -159,6 +163,12 @@ class ShardedStore:
         #: mutable injection hook; set a :class:`FaultPlan` to script the
         #: next IO failure, clear to heal the store.
         self.fault_plan: Optional[FaultPlan] = None
+
+    def _exact(self, path) -> bool:
+        """Whether a leaf at tree ``path`` is kept out of compression."""
+        names = {str(getattr(k, "key", getattr(k, "name", None)))
+                 for k in path}
+        return not names.isdisjoint(self.cfg.no_compress_paths)
 
     def fault(self, point: str,
               abort: Optional[threading.Event] = None
@@ -180,18 +190,19 @@ class ShardedStore:
         most an uncommitted (manifest-less) generation behind.
         """
         t0 = time.perf_counter()
-        leaves, treedef = jax.tree.flatten(tree)
+        leaves, treedef = jax.tree_util.tree_flatten_with_path(tree)
         gen = self.root / f"step_{step:09d}"
         gen.mkdir(parents=True, exist_ok=True)
 
         arrays = {}
         meta_leaves = []
-        for i, leaf in enumerate(leaves):
+        for i, (path, leaf) in enumerate(leaves):
             arr = np.asarray(leaf)
             entry = {"index": i, "dtype": str(arr.dtype),
                      "shape": list(arr.shape), "compressed": False}
             if (self.cfg.compress and arr.dtype in (np.float32,)
-                    and arr.size >= 4096):
+                    and arr.size >= 4096
+                    and not self._exact(path)):
                 q, s, pad = kops.quantize_array(jax.numpy.asarray(arr))
                 arrays[f"leaf_{i}_q"] = np.asarray(q)
                 arrays[f"leaf_{i}_s"] = np.asarray(s)

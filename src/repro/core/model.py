@@ -370,10 +370,7 @@ def K_dE_dT_autodiff(T, ckpt: CheckpointParams, power: PowerParams,
     the local enable_x64 context; global JAX dtype state untouched)."""
     import jax
     import jax.numpy as jnp
-    try:  # newer jax re-exports the x64 context at top level
-        from jax import enable_x64
-    except ImportError:
-        from jax.experimental import enable_x64
+    from jax import enable_x64
 
     C, R, D, mu, omega = ckpt.C, ckpt.R, ckpt.D, ckpt.mu, ckpt.omega
     a, b = ckpt.a, ckpt.b

@@ -14,10 +14,7 @@ import dataclasses
 
 import jax
 
-try:  # jax >= 0.5 exposes explicit axis types on the mesh
-    from jax.sharding import AxisType
-except ImportError:  # older jax: meshes are implicitly Auto on every axis
-    AxisType = None
+from jax.sharding import AxisType
 
 from ..parallel import sharding as shd
 
@@ -60,11 +57,8 @@ def build_mesh(plan: ElasticPlan):
     for s in shape:
         need *= s
     devs = jax.devices()[:need]
-    if AxisType is not None:
-        return jax.make_mesh(shape, names,
-                             devices=devs,
-                             axis_types=(AxisType.Auto,) * len(shape))
-    return jax.make_mesh(shape, names, devices=devs)
+    return jax.make_mesh(shape, names, devices=devs,
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 
 def reshard_tree(tree, spec_tree, new_mesh, rules=None):

@@ -4,10 +4,15 @@ The sim engine's fast path (``sim/engine.py::_run_one_event``) is a
 ``lax.scan`` with one iteration per FAILURE, double-vmapped over
 (grid points x trials).  This kernel is the accelerator-native port:
 
-* grid = ``(points/bp, trials/bt)`` blocks; each block owns its
-  ``(bp, bt)`` tile of trajectory state in registers/VMEM and streams the
-  failure-gap schedule ``(bp, bt, F)`` through VMEM, one gap slab per
-  loop iteration via a dynamic slice on the capacity axis.
+* the failure-gap schedule is laid out capacity-first, ``(F, B, N)``, so
+  one gap slab ``gaps_ref[g]`` is a whole ``(bp, bt)`` tile: the dynamic
+  index is on the untiled leading axis, never on the 128-lane axis.
+* grid = ``(points/bp, trials/bt, F/fb)``.  Each ``(bp, bt)`` tile of
+  trajectories keeps its state in VMEM scratch while the capacity axis
+  streams through in ``(fb, bp, bt)`` gap blocks (the last grid axis,
+  sequential).  The VMEM a tile needs is therefore bounded by ``fb``
+  whatever capacity bucket the engine dispatches: ``fb`` is sized so
+  the double-buffered gap block stays within :data:`GAP_BLOCK_BYTES`.
 * the closed-form between-failure arithmetic is kept TERM-FOR-TERM from
   ``_run_one_event`` (same expressions, same parenthesization, same
   select ordering), so in f64 the kernel is bit-identical to the scan —
@@ -18,12 +23,13 @@ The sim engine's fast path (``sim/engine.py::_run_one_event``) is a
   ``n_fail == i`` and one uniform slab load per iteration serves every
   active lane; done lanes read a stale slab and discard it in the same
   select the scan kernel uses.
-* unlike the fixed-length scan, the loop is a ``while_loop`` that exits
-  as soon as every lane in the block is done.  Post-completion
-  iterations are identities under the done-select, so the exit is
-  bit-exact — it only skips the power-of-two padding tail the scan
-  kernel burns through, which is where the speedup on CPU interpret
-  mode comes from (BENCH_sweep.json ``pallas_event_engine``).
+* unlike the fixed-length scan, each gap block runs a ``while_loop``
+  that exits as soon as every lane in the tile is done (and later
+  blocks run no iteration at all).  Post-completion iterations are
+  identities under the done-select, so the exit is bit-exact.
+* Mosaic constraints: the flags (``used_inf``, ``done``, the two bool
+  outputs) are int32 in the kernel, and every loop carry starts from a
+  tile loaded out of scratch rather than from a splat constant.
 
 Precision follows the engine's :class:`~repro.sim.precision
 .PrecisionPolicy`: under ``f64`` the state updates are the scan
@@ -34,12 +40,11 @@ increments and selected BEFORE accumulation, and the remaining-work
 read uses the corrected ``committed + c`` — the parity gates in
 tests/test_pallas_engine.py bound the result against the f64 oracle.
 
-On CPU the wrapper falls back to ``pallas_call(..., interpret=True)``
-(traced to plain XLA ops, jit-compatible) so tier-1 parity runs
-everywhere; on TPU it lowers to Mosaic.  The full capacity axis rides
-in one block — at the default tile ``8 x 128`` lanes an f32 schedule
-budget of F = 4096 gaps is ~16 MiB of VMEM; shrink ``block_trials``
-for fatter schedules.
+On the CPU backend the wrapper runs ``pallas_call(..., interpret=True)``
+(traced to plain XLA ops, jit-compatible) so tier-1 parity runs there;
+on a TPU it lowers to Mosaic, which has no f64 — the ``f64`` policy is
+refused there.  Any other backend is an error, never a silent
+interpret-mode fallback.
 """
 from __future__ import annotations
 
@@ -49,27 +54,51 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ..sim.precision import comp_add
+from .ops import interpret_mode
 
 #: work-completion slack — MUST match sim/engine.py::_EPS term-for-term.
 _EPS = 1e-12
 
+#: VMEM the double-buffered gap block of one tile may take (bytes).  At
+#: the default ``8 x 128`` tile in f32 this streams 1024 gaps per block;
+#: the scoped-VMEM limit Mosaic applies by default is 16 MiB.
+GAP_BLOCK_BYTES = 8 << 20
 
-def _interpret(force: bool | None) -> bool:
-    if force is not None:
-        return force
-    return jax.default_backend() != "tpu"
+
+def gap_block(F: int, bp: int, bt: int, itemsize: int) -> int:
+    """Gaps per streamed block: the whole capacity when it fits, else the
+    largest power of two whose double buffer fits GAP_BLOCK_BYTES."""
+    per_gap = 2 * bp * bt * itemsize
+    fb = max(1, GAP_BLOCK_BYTES // per_gap)
+    if F <= fb:
+        return F
+    return 1 << (fb.bit_length() - 1)
 
 
 def _event_kernel(T_ref, C_ref, R_ref, D_ref, O_ref, TB_ref, gaps_ref,
                   wall_ref, work_ref, io_ref, down_ref, nfail_ref,
-                  nckpt_ref, trunc_ref, ginf_ref, *, n_steps: int,
+                  nckpt_ref, trunc_ref, ginf_ref, *state_refs, n_steps: int,
                   n_gaps: int, compensated: bool):
     f = gaps_ref.dtype
     zero = jnp.zeros((), f)
     one = jnp.ones((), f)
-    bp, bt = wall_ref.shape
+    # int32 scalars: a Python int would trace as int64 under the caller's
+    # x64 context, which Mosaic cannot lower.
+    i32 = lambda v: jnp.asarray(v, jnp.int32)
+    fb = gaps_ref.shape[0]
+    k_blk = pl.program_id(2)
+    last_blk = pl.num_programs(2) - 1
+
+    # state_refs = (wall, committed, work_exec, io_time, down_time,
+    #               n_fail, n_ckpt, used_inf, done [, 5 Neumaier c-terms])
+    # — VMEM scratch that persists across the capacity grid axis.
+    @pl.when(k_blk == 0)
+    def _init():
+        for r in state_refs:
+            r[...] = jnp.zeros(r.shape, r.dtype)
 
     T = T_ref[...]                       # (bp, 1) — broadcasts over trials
     C = C_ref[...]
@@ -81,32 +110,29 @@ def _event_kernel(T_ref, C_ref, R_ref, D_ref, O_ref, TB_ref, gaps_ref,
     w = T - (one - omega) * C            # work committed per full period
     omega_safe = jnp.where(omega > zero, omega, one)
 
-    fz = jnp.zeros((bp, bt), f)
-    iz = jnp.zeros((bp, bt), jnp.int32)
-    bz = jnp.zeros((bp, bt), jnp.bool_)
-    # state = (i, wall, committed, work_exec, io_time, down_time,
-    #          n_fail, n_ckpt, used_inf, done [, 5 Neumaier c-terms])
-    state = (jnp.zeros((), jnp.int32), fz, fz, fz, fz, fz, iz, iz, bz, bz)
-    if compensated:
-        state = state + (fz, fz, fz, fz, fz)
+    # This block serves failures [lo, hi); the last block also runs the
+    # past-the-schedule iterations (inf gap) up to the step budget.
+    lo = k_blk * fb
+    hi = jnp.where(k_blk == last_blk, i32(n_steps),
+                   jnp.minimum(lo + fb, i32(n_steps)))
 
-    def cond(state):
-        return (state[0] < n_steps) & jnp.logical_not(jnp.all(state[9]))
+    def cond(carry):
+        # "some lane not done" as an int32 min (jnp.all's bool reduction
+        # does not lower under x64).
+        return (carry[0] < hi) & (jnp.min(carry[9]) == 0)
 
-    def body(state):
+    def body(carry):
         (i, wall, committed, work_exec, io_time, down_time,
-         n_fail, n_ckpt, used_inf, done) = state[:10]
+         n_fail, n_ckpt, used_inf, done) = carry[:10]
         if compensated:
-            c_wall, c_comm, c_work, c_io, c_down = state[10:]
+            c_wall, c_comm, c_work, c_io, c_down = carry[10:]
 
         # Uniform slab read: active lanes have n_fail == i (see module
-        # docstring), so one dynamic slice on the capacity axis replaces
+        # docstring), so one dynamic index on the capacity axis replaces
         # the scan kernel's per-lane gather; past-the-schedule reads are
         # inf == "no more failures", flagging exhaustion.
         in_range = i < n_gaps
-        gi = jnp.minimum(i, n_gaps - 1)
-        slab = pl.load(gaps_ref, (slice(None), slice(None),
-                                  pl.dslice(gi, 1)))[:, :, 0]
+        slab = gaps_ref[jnp.clip(i - lo, i32(0), i32(fb - 1))]
         g = jnp.where(in_range, slab, jnp.asarray(jnp.inf, f))
 
         # ---- closed-form completion time from this segment start ----
@@ -130,7 +156,12 @@ def _event_kernel(T_ref, C_ref, R_ref, D_ref, O_ref, TB_ref, gaps_ref,
         def sel(a_val, b_val):
             return jnp.where(complete, a_val, b_val)
 
-        keep = lambda old, upd: jnp.where(done, old, upd)
+        is_done = done != 0
+        keep = lambda old, upd: jnp.where(is_done, old, upd)
+        flags = (jnp.where(in_range, used_inf, i32(1)),
+                 jnp.where(complete, i32(1), done))
+        counts = (sel(n_fail, n_fail + 1).astype(jnp.int32),
+                  (n_ckpt + sel(j, k).astype(jnp.int32)).astype(jnp.int32))
 
         if not compensated:
             wall_a = wall + t_fin
@@ -147,13 +178,9 @@ def _event_kernel(T_ref, C_ref, R_ref, D_ref, O_ref, TB_ref, gaps_ref,
                    sel(committed, committed_b),
                    sel(work_a, work_b),
                    sel(io_a, io_b),
-                   sel(down_time, down_time + D),
-                   sel(n_fail, n_fail + 1).astype(jnp.int32),
-                   (n_ckpt + sel(j, k).astype(jnp.int32)).astype(jnp.int32),
-                   jnp.logical_or(used_inf, ~in_range),
-                   jnp.logical_or(done, complete))
+                   sel(down_time, down_time + D)) + counts + flags
             return (i + 1,) + tuple(
-                keep(o, u_) for o, u_ in zip(state[1:10], new))
+                keep(o, u_) for o, u_ in zip(carry[1:10], new))
 
         # Compensated policy: form each branch's CONTRIBUTION, select it,
         # then fold it into the Neumaier pair; the done-select freezes
@@ -169,33 +196,35 @@ def _event_kernel(T_ref, C_ref, R_ref, D_ref, O_ref, TB_ref, gaps_ref,
             (wall, c_wall, inc_wall), (committed, c_comm, inc_comm),
             (work_exec, c_work, inc_work), (io_time, c_io, inc_io),
             (down_time, c_down, inc_down))]
-        new = tuple(p[0] for p in pairs) + (
-            sel(n_fail, n_fail + 1).astype(jnp.int32),
-            (n_ckpt + sel(j, k).astype(jnp.int32)).astype(jnp.int32),
-            jnp.logical_or(used_inf, ~in_range),
-            jnp.logical_or(done, complete))
+        new = tuple(p[0] for p in pairs) + counts + flags
         new_c = tuple(p[1] for p in pairs)
         return ((i + 1,)
-                + tuple(keep(o, u_) for o, u_ in zip(state[1:10], new))
-                + tuple(keep(o, u_) for o, u_ in zip(state[10:], new_c)))
+                + tuple(keep(o, u_) for o, u_ in zip(carry[1:10], new))
+                + tuple(keep(o, u_) for o, u_ in zip(carry[10:], new_c)))
 
-    state = lax.while_loop(cond, body, state)
-    (_, wall, committed, work_exec, io_time, down_time,
-     n_fail, n_ckpt, used_inf, done) = state[:10]
-    if compensated:
-        c_wall, c_comm, c_work, c_io, c_down = state[10:]
-        wall = wall + c_wall
-        work_exec = work_exec + c_work
-        io_time = io_time + c_io
-        down_time = down_time + c_down
-    wall_ref[...] = wall
-    work_ref[...] = work_exec
-    io_ref[...] = io_time
-    down_ref[...] = down_time
-    nfail_ref[...] = n_fail
-    nckpt_ref[...] = n_ckpt
-    trunc_ref[...] = ~done
-    ginf_ref[...] = used_inf
+    carry = (lo,) + tuple(r[...] for r in state_refs)
+    carry = lax.while_loop(cond, body, carry)
+    for r, v in zip(state_refs, carry[1:]):
+        r[...] = v
+
+    @pl.when(k_blk == last_blk)
+    def _finish():
+        (wall, _, work_exec, io_time, down_time,
+         n_fail, n_ckpt, used_inf, done) = carry[1:10]
+        if compensated:
+            c_wall, _, c_work, c_io, c_down = carry[10:]
+            wall = wall + c_wall
+            work_exec = work_exec + c_work
+            io_time = io_time + c_io
+            down_time = down_time + c_down
+        wall_ref[...] = wall
+        work_ref[...] = work_exec
+        io_ref[...] = io_time
+        down_ref[...] = down_time
+        nfail_ref[...] = n_fail
+        nckpt_ref[...] = n_ckpt
+        trunc_ref[...] = i32(1) - done
+        ginf_ref[...] = used_inf
 
 
 def event_sweep(T, C, R, D, omega, T_base, gaps, *, n_steps: int,
@@ -215,30 +244,47 @@ def event_sweep(T, C, R, D, omega, T_base, gaps, *, n_steps: int,
     exit still fires; their outputs are sliced off.
     """
     dt = jnp.dtype(dtype)
+    interpret = interpret_mode(force_interpret)
+    if not interpret and dt.itemsize > 4:
+        raise ValueError(f"the Pallas event kernel cannot compute in {dt} "
+                         f"on a TPU (Mosaic has no f64); use the "
+                         f"compensated_f32 policy or engine_kind='event'")
     gaps = jnp.asarray(gaps, dt)
     B, N, F = gaps.shape
     bp = min(int(block_points), B)  # reprolint: disable=RPL004 (keyword-only static Python int by contract — block shapes must be concrete to build the pallas grid)
     bt = min(int(block_trials), N)  # reprolint: disable=RPL004 (keyword-only static Python int by contract — block shapes must be concrete to build the pallas grid)
+    fb = gap_block(F, bp, bt, dt.itemsize)
     Bp = -(-B // bp) * bp
     Np = -(-N // bt) * bt
+    Fp = -(-F // fb) * fb
     col = lambda x: jnp.pad(jnp.asarray(x, dt).reshape(B, 1),
                             ((0, Bp - B), (0, 0)), mode="edge")
-    gaps = jnp.pad(gaps, ((0, Bp - B), (0, Np - N), (0, 0)), mode="edge")
+    # Capacity-first layout; the capacity padding is never read as a gap
+    # (the kernel treats every index >= F as past the schedule).
+    gaps = jnp.pad(jnp.transpose(gaps, (2, 0, 1)),
+                   ((0, Fp - F), (0, Bp - B), (0, Np - N)), mode="edge")
 
     kernel = functools.partial(_event_kernel, n_steps=int(n_steps),  # reprolint: disable=RPL004 (static loop bound — the while_loop's worst-case trip count is baked into the kernel)
                                n_gaps=F, compensated=bool(compensated))
-    pspec = pl.BlockSpec((bp, 1), lambda i, j: (i, 0))
-    ospec = pl.BlockSpec((bp, bt), lambda i, j: (i, j))
+    # (An int32 zero: a literal 0 would be int64 under the x64 context.)
+    pspec = pl.BlockSpec((bp, 1), lambda i, j, k: (i, jnp.int32(0)))
+    ospec = pl.BlockSpec((bp, bt), lambda i, j, k: (i, j))
     oshape = lambda d: jax.ShapeDtypeStruct((Bp, Np), d)
+    n_float = 5 + (5 if compensated else 0)
+    scratch = ([pltpu.VMEM((bp, bt), dt)] * 5
+               + [pltpu.VMEM((bp, bt), jnp.int32)] * 4
+               + [pltpu.VMEM((bp, bt), dt)] * (n_float - 5))
     outs = pl.pallas_call(
         kernel,
-        grid=(Bp // bp, Np // bt),
-        in_specs=[pspec] * 6 + [pl.BlockSpec((bp, bt, F),
-                                             lambda i, j: (i, j, 0))],
+        grid=(Bp // bp, Np // bt, Fp // fb),
+        in_specs=[pspec] * 6 + [pl.BlockSpec((fb, bp, bt),
+                                             lambda i, j, k: (k, i, j))],
         out_specs=[ospec] * 8,
-        out_shape=[oshape(dt)] * 4 + [oshape(jnp.int32)] * 2
-                  + [oshape(jnp.bool_)] * 2,
-        interpret=_interpret(force_interpret),
+        out_shape=[oshape(dt)] * 4 + [oshape(jnp.int32)] * 4,
+        scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
     )(col(T), col(C), col(R), col(D), col(omega), col(T_base), gaps)
     wall, work, io, down, n_fail, n_ckpt, trunc, ginf = (
         o[:B, :N] for o in outs)
@@ -246,4 +292,4 @@ def event_sweep(T, C, R, D, omega, T_base, gaps, *, n_steps: int,
     return {"wall_time": as_f64(wall), "work_executed": as_f64(work),
             "io_time": as_f64(io), "down_time": as_f64(down),
             "n_failures": n_fail, "n_checkpoints": n_ckpt,
-            "truncated": trunc, "gaps_exhausted": ginf}
+            "truncated": trunc != 0, "gaps_exhausted": ginf != 0}

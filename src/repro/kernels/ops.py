@@ -1,9 +1,10 @@
 """jit'd public wrappers around the Pallas kernels.
 
 Handles layout adaptation (model layouts <-> kernel layouts), padding to
-block multiples, and backend dispatch: on CPU the kernels execute in
-``interpret=True`` mode (Python emulation — used by all tests); on TPU they
-lower to Mosaic.  ``force_interpret`` pins interpret mode for testing.
+block multiples, and backend dispatch (:func:`interpret_mode`): on the CPU backend the
+kernels execute in ``interpret=True`` mode (used by all tests); on a TPU
+they lower to Mosaic; any other backend raises.  ``force_interpret``
+pins the mode (tests interpret; the v5e compile tests lower).
 """
 from __future__ import annotations
 
@@ -18,10 +19,19 @@ from . import quant_blockwise as _qb
 from . import rglru_scan as _rg
 
 
-def _interpret(force: bool | None) -> bool:
+def interpret_mode(force: bool | None = None) -> bool:
+    """Whether a Pallas kernel runs in interpret mode: ``force`` when
+    given, else True on the CPU backend and False on a TPU.  Any other
+    backend raises — interpret mode must never hide a missing chip."""
     if force is not None:
         return force
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
+        return False
+    raise RuntimeError(f"no Pallas lowering for backend {backend!r}; "
+                       f"the kernels run on a TPU or interpreted on CPU")
 
 
 # ---------------------------------------------------------------------------
@@ -37,7 +47,7 @@ def flash_attention(q, k, v, *, mode: str = "causal", window: int = 0,
     out = _fa.flash_attention(
         fold(q), fold(k), fold(v), mode=mode, window=window, chunk=chunk,
         qb=min(256, S), kb=min(256, k.shape[1]),
-        interpret=_interpret(force_interpret))
+        interpret=interpret_mode(force_interpret))
     return out.reshape(B, H, S, Dh).transpose(0, 2, 1, 3)
 
 
@@ -50,7 +60,7 @@ def rglru_scan(a, b, h0, *, force_interpret: bool | None = None):
     B, S, W = a.shape
     return _rg.rglru_scan(a, b, h0, bb=min(8, B), sb=min(256, S),
                           wb=min(128, W),
-                          interpret=_interpret(force_interpret))
+                          interpret=interpret_mode(force_interpret))
 
 
 # ---------------------------------------------------------------------------
@@ -65,7 +75,7 @@ def mlstm_scan(q, k, v, li, lf, *, chunk: int = 256,
     fold2 = lambda t: t.reshape(B * H, S)
     out = _ml.mlstm_scan(fold(q), fold(k), fold(v), fold2(li), fold2(lf),
                          chunk=min(chunk, S),
-                         interpret=_interpret(force_interpret))
+                         interpret=interpret_mode(force_interpret))
     return out.reshape(B, H, S, Dh)
 
 
@@ -73,9 +83,20 @@ def mlstm_scan(q, k, v, li, lf, *, chunk: int = 256,
 # Blockwise int8 quantization (arbitrary arrays)
 # ---------------------------------------------------------------------------
 
+#: row block of the quantize kernels: a multiple of 32, the int8 sublane
+#: tile of a TPU.
+_QUANT_ROWS = 256
+
+
 def _pad_of(size: int) -> tuple:
+    """(pad, D): ``size + pad`` elements as ``(rows, D)``, where ``rows``
+    is either at most one row block (the block is then the whole array)
+    or a multiple of it, so every block is tile-aligned on a TPU."""
     D = 512 if size >= 512 else 128
-    return (-size) % D, D
+    rows = -(-size // D)
+    if rows > _QUANT_ROWS:
+        rows = -(-rows // _QUANT_ROWS) * _QUANT_ROWS
+    return rows * D - size, D
 
 
 @functools.partial(jax.jit, static_argnames=("pad", "D", "force_interpret"))
@@ -84,8 +105,8 @@ def _quantize_2d(x, *, pad: int, D: int, force_interpret: bool | None):
     if pad:
         flat = jnp.concatenate([flat, jnp.zeros((pad,), flat.dtype)])
     x2 = flat.reshape(-1, D)
-    return _qb.quantize(x2, bn=min(256, x2.shape[0]),
-                        interpret=_interpret(force_interpret))
+    return _qb.quantize(x2, bn=min(_QUANT_ROWS, x2.shape[0]),
+                        interpret=interpret_mode(force_interpret))
 
 
 def quantize_array(x, *, force_interpret: bool | None = None):
@@ -100,8 +121,8 @@ def quantize_array(x, *, force_interpret: bool | None = None):
 def dequantize_array(q, s, *, shape, dtype, pad: int,
                      force_interpret: bool | None = None):
     x2 = _qb.dequantize(q, s, dtype=jnp.dtype(dtype),
-                        bn=min(256, q.shape[0]),
-                        interpret=_interpret(force_interpret))
+                        bn=min(_QUANT_ROWS, q.shape[0]),
+                        interpret=interpret_mode(force_interpret))
     flat = x2.reshape(-1)
     if pad:
         flat = flat[:-pad]

@@ -13,10 +13,7 @@ from typing import Optional
 import jax
 from jax.sharding import Mesh
 
-try:  # jax >= 0.5 exposes explicit axis types on the mesh
-    from jax.sharding import AxisType
-except ImportError:  # older jax: meshes are implicitly Auto on every axis
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -55,17 +52,14 @@ def _mesh(shape, axes) -> Mesh:
             f"dry-run entry point must set "
             f"XLA_FLAGS=--xla_force_host_platform_device_count={need} "
             f"BEFORE importing jax.")
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             devices=devs[:need],
-                             axis_types=(AxisType.Auto,) * len(shape))
-    return jax.make_mesh(shape, axes, devices=devs[:need])
+    return jax.make_mesh(shape, axes, devices=devs[:need],
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 
 #: TPU v5e hardware constants used by the roofline analysis (per chip).
 TPU_V5E = {
     "peak_bf16_flops": 197e12,       # FLOP/s
     "hbm_bandwidth": 819e9,          # B/s
-    "ici_link_bandwidth": 50e9,      # B/s per link
+    "ici_bandwidth": 200e9,          # B/s per chip (1,600 Gbit/s)
     "hbm_bytes": 16 * 2**30,         # 16 GiB
 }

@@ -18,8 +18,8 @@ benchmarks exercise):
   that turns pure solver code into silent nondeterminism).
 
 Budgets carry slack of ``max(4, 25%)`` over the measured count so
-jax-version drift across the CI matrix does not trip the gate, while a
-per-point compile explosion (O(grid size) programs) still does.
+small compile-behavior drift does not trip the gate, while a per-point
+compile explosion (O(grid size) programs) still does.
 
 Regenerate the committed budgets after a deliberate compile-behavior
 change (new kernel, different bucketing) the same way the bench
@@ -47,10 +47,9 @@ from typing import Callable, Dict, Iterator, Optional
 BENCH_PATH = Path(__file__).resolve().parents[2] / "BENCH_sweep.json"
 BUDGET_KEY = "recompile_budget"
 
-#: loggers that emit the ``Compiling <name> ...`` records across the
-#: supported jax range (0.4.x logs from the pxla interpreter; keep the
-#: dispatch logger too for older/newer layouts).
-_COMPILE_LOGGERS = ("jax._src.interpreters.pxla", "jax._src.dispatch")
+#: the logger that emits jax's ``Compiling <name> ...`` records (one per
+#: lowered program, persistent-cache hit or not).
+_COMPILE_LOGGERS = ("jax._src.interpreters.pxla",)
 
 
 class CompileCounter(logging.Handler):

@@ -15,9 +15,10 @@ Layers:
   precision — per-backend :class:`PrecisionPolicy` (f64 oracle on CPU,
               compensated f32 on accelerators) with documented parity
               tolerances; resolved per call via ``resolve_precision``.
-  cache     — persistent XLA compilation-cache wiring (cold-start compile
-              paid once per machine, not once per process); auto-enabled
-              when ``$REPRO_COMPILE_CACHE`` is set.
+  cache     — persistent XLA compilation cache (cold-start compile paid
+              once per cache directory, not once per process): in
+              ``$JAX_COMPILATION_CACHE_DIR`` when set, else in the
+              checkout's ``.jax_cache``.
 
 The scalar ``repro.core.simulator.simulate_once`` remains the reference
 oracle; ``tests/test_sim_engine.py`` pins the batched engine to it
@@ -25,8 +26,7 @@ trajectory-for-trajectory, and ``tests/test_dispatch.py`` pins the
 sharded/chunked execution paths to the single-device single-chunk results
 bit-for-bit.
 """
-from .cache import (enable_compile_cache, maybe_enable_from_env,
-                    active_cache_dir)
+from .cache import enable_compile_cache
 from .dispatch import (DispatchConfig, default_config, sweep_mesh,
                        cache_stats, reset_cache_stats,
                        BackendInfo, backend_info, resolve_precision)
@@ -55,6 +55,5 @@ from .sweep import (GridResult, MultilevelGridResult, RobustnessResult,
                     ml_time_final_batched, ml_energy_final_batched,
                     sweep_rho_grid, sweep_mu_rho_grid, sweep_nodes_grid)
 
-# Persistent compile cache: opt-in via $REPRO_COMPILE_CACHE (no-op
-# otherwise; see sim/cache.py).
-maybe_enable_from_env()
+# Persistent compile cache, on before the first jitted call (sim/cache.py).
+enable_compile_cache()

@@ -1,87 +1,42 @@
-"""Persistent XLA compilation cache wiring.
+"""Persistent XLA compilation cache: placed from outside, or in the checkout.
 
-The batched sweeps and the MC engine pay ~1-2 s of XLA compilation per
-process (``BENCH_sweep.json``: ``batched_cold_s``), once per compiled
-program.  JAX can persist compiled executables on disk
-(``jax_compilation_cache_dir``), so the compile cost is paid once per
-*machine* instead of once per *process* — the ``cold_start_cached``
-benchmark entry gates the resulting cold-start reduction.
+Compiling the sweep, engine and trainer programs is a large part of a
+cold run, so every process keeps JAX's persistent compilation cache on
+and pays each compile once per cache directory instead of once per
+process.  The directory is fixed, because the path is part of what JAX
+finds again:
 
-Enable it explicitly::
+* ``$JAX_COMPILATION_CACHE_DIR`` when it is set.  JAX reads that variable
+  itself, and this module then sets no directory at all.
+* otherwise :data:`CHECKOUT_DIR`, ``.jax_cache`` at the root of the
+  checkout (gitignored).
 
-    from repro.sim import enable_compile_cache
-    enable_compile_cache("/path/to/cache")     # or no arg: env / default
-
-or via the environment (picked up automatically when ``repro.sim`` is
-imported)::
-
-    REPRO_COMPILE_CACHE=/path/to/cache python my_sweep.py
-
-The helper also drops JAX's minimum-compile-time / minimum-entry-size
-thresholds so the CPU-sized programs this repo compiles (~0.3-2 s) are
-actually cached; on jax versions without those knobs it degrades to just
-setting the cache directory.
+:func:`enable_compile_cache` applies the rule; ``import repro.sim`` calls
+it.  JAX's own thresholds stay in force (by default only programs that
+take at least a second to compile are written), and
+``jax_enable_compilation_cache`` still turns the cache off.
 """
 from __future__ import annotations
 
 import os
 from pathlib import Path
-from typing import Optional
 
-#: environment variable naming the cache directory.
-ENV_VAR = "REPRO_COMPILE_CACHE"
+#: the environment variable JAX reads its cache directory from.
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
 
-#: fallback directory when enabled explicitly with no path and no env.
-DEFAULT_DIR = Path.home() / ".cache" / "repro" / "jax-compile-cache"
-
-_active_dir: Optional[str] = None
+#: the cache directory when ``$JAX_COMPILATION_CACHE_DIR`` is not set.
+CHECKOUT_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
 
 
-def enable_compile_cache(path: Optional[str] = None) -> str:
-    """Point JAX at a persistent on-disk compilation cache (idempotent).
+def enable_compile_cache() -> str:
+    """Point JAX at the cache directory of the rule above; returns it.
 
-    Resolution order: explicit ``path`` > ``$REPRO_COMPILE_CACHE`` >
-    ``~/.cache/repro/jax-compile-cache``.  Returns the directory used.
-    Safe to call before or after the first jit — only programs compiled
-    afterwards are cached.
-    """
-    global _active_dir
+    Only programs compiled afterwards are cached, so this runs when
+    ``repro.sim`` is imported, before the first jitted call."""
+    env = os.environ.get(ENV_VAR)
+    if env:
+        return env
     import jax
 
-    target = str(path or os.environ.get(ENV_VAR) or DEFAULT_DIR)
-    Path(target).mkdir(parents=True, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", target)
-    for knob, value in (("jax_persistent_cache_min_compile_time_secs", 0.0),
-                        ("jax_persistent_cache_min_entry_size_bytes", -1)):
-        try:
-            jax.config.update(knob, value)
-        except (AttributeError, KeyError):   # knob absent on this jax
-            pass
-    _active_dir = target
-    return target
-
-
-def maybe_enable_from_env() -> Optional[str]:
-    """Enable the cache iff ``$REPRO_COMPILE_CACHE`` is set (the
-    ``repro.sim`` import hook); returns the directory or None.
-
-    Unlike the explicit :func:`enable_compile_cache` call, a failure here
-    (unwritable path, read-only home in a container) degrades to a
-    warning — an opt-in performance env var must not turn ``import
-    repro.sim`` into a hard crash.
-    """
-    if not os.environ.get(ENV_VAR):
-        return None
-    try:
-        return enable_compile_cache()
-    except OSError as e:
-        import warnings
-        warnings.warn(f"{ENV_VAR}={os.environ[ENV_VAR]!r} unusable "
-                      f"({e}); continuing without a persistent compile "
-                      f"cache", RuntimeWarning, stacklevel=2)
-        return None
-
-
-def active_cache_dir() -> Optional[str]:
-    """The directory the cache was enabled with, or None."""
-    return _active_dir
+    jax.config.update("jax_compilation_cache_dir", str(CHECKOUT_DIR))
+    return str(CHECKOUT_DIR)

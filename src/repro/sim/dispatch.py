@@ -75,15 +75,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # newer jax re-exports the x64 context at top level
-    from jax import enable_x64
-except ImportError:
-    from jax.experimental import enable_x64
-
-try:  # jax >= 0.5 promotes shard_map out of experimental
-    from jax import shard_map  # type: ignore[attr-defined]
-except ImportError:
-    from jax.experimental.shard_map import shard_map
+from jax import enable_x64, shard_map
 
 from . import precision as _precision
 # Re-exported so callers configure precision where they configure
@@ -251,8 +243,8 @@ class DispatchConfig:
 def _env_int(name: str):
     """Parse an optional integer env knob; a malformed value degrades to
     a warning + default instead of crashing every grid entry point from
-    deep inside a sweep (same contract as ``cache.maybe_enable_from_env``
-    — opt-in performance knobs must not become hard crashes)."""
+    deep inside a sweep (opt-in performance knobs must not become hard
+    crashes)."""
     raw = os.environ.get(name)
     if not raw:
         return None
@@ -279,19 +271,10 @@ def resolve(config: Optional[DispatchConfig]) -> DispatchConfig:
 
 def _backend_devices(backend: Optional[str] = None) -> list:
     """The jax devices of ``backend`` (a platform name); None = the
-    process default platform.  An unavailable platform degrades to a
-    warning + default devices — backend selection is an opt-in knob and
-    must not turn every sweep into a hard crash on a CPU-only box."""
-    if not backend:
-        return jax.devices()
-    try:
-        return jax.devices(backend)
-    except RuntimeError:
-        import warnings
-        warnings.warn(f"backend {backend!r} has no devices here; using "
-                      f"the default platform ({jax.default_backend()})",
-                      RuntimeWarning, stacklevel=3)
-        return jax.devices()
+    process default platform.  A platform with no devices here raises
+    (JAX's own RuntimeError): a sweep never runs silently on another
+    device than the one asked for."""
+    return jax.devices(backend) if backend else jax.devices()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -485,7 +468,7 @@ def _runner_for(key, build, ndev: int, in_axes: Sequence[Optional[int]],
         fn = jax.jit(shard_map(build, mesh=sweep_mesh(ndev, backend),
                                in_specs=in_specs,
                                out_specs=_out_spec_tree(out_axes),
-                               check_rep=False))
+                               check_vma=False))
     _RUNNERS.put(ck, fn)
     return fn
 

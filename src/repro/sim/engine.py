@@ -103,10 +103,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-try:  # newer jax re-exports the x64 context at top level
-    from jax import enable_x64
-except ImportError:
-    from jax.experimental import enable_x64
+from jax import enable_x64
 
 from ..core.failures import as_process
 from . import dispatch as _dispatch
@@ -742,7 +739,12 @@ def _sampled_build(proc_fn, cap_sample: int, cap_used: int,
                     return proc_fn(jax.random.fold_in(kp, ti),
                                    (cap_sample,), m, pp)
                 return jax.vmap(sample_trial)(t_idx)
-            gaps = jax.vmap(sample_point)(mean, idx, *params)
+            # One point per loop iteration: the loop writes the f64
+            # schedule out before the kernel's cast and relayout.  Fused
+            # into them (under vmap), the emulated-f64 sampler takes the
+            # TPU compiler minutes instead of seconds.
+            gaps = lax.map(lambda a: sample_point(*a),
+                           (mean, idx) + tuple(params))
             return run_grid(T, C, R, D, omega, Tb, gaps[:, :, :cap_used])
         return build
     kernel = _KERNELS[kind]

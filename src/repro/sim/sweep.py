@@ -24,10 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-try:  # newer jax re-exports the x64 context at top level
-    from jax import enable_x64
-except ImportError:
-    from jax.experimental import enable_x64
+from jax import enable_x64
 
 from ..core.params import PowerParams
 from . import dispatch as _dispatch

@@ -93,6 +93,21 @@ class TestStore:
                     / jnp.max(jnp.abs(tree["w"])))
         assert rel < 0.01
 
+    def test_compression_keeps_adamw_second_moment_exact(self, tmp_path):
+        """AdamW's ``v`` is restored bit for bit (an entry rounded to zero
+        would divide the next update by ~eps); ``m`` is compressed."""
+        from repro.optim import adamw
+        params = {"w": jax.random.normal(jax.random.key(0), (128, 128))}
+        st = adamw.init_state(params)
+        v = jnp.exp(jax.random.normal(jax.random.key(1), (128, 128)) * 8)
+        st = st._replace(m={"w": params["w"]}, v={"w": v})
+        comp = ShardedStore(StoreConfig(root=str(tmp_path), compress=True))
+        comp.save(1, (params, st))
+        (_, out), _ = comp.restore((params, st))
+        np.testing.assert_array_equal(np.asarray(out.v["w"]), np.asarray(v))
+        assert not np.array_equal(np.asarray(out.m["w"]),
+                                  np.asarray(params["w"]))
+
     def test_restore_empty_store(self, tmp_path):
         store = ShardedStore(StoreConfig(root=str(tmp_path)))
         out, step = store.restore(small_tree())

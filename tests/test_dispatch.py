@@ -9,6 +9,7 @@ itself is launched with ``XLA_FLAGS=--xla_force_host_platform_device_count=N``
 host; one subprocess test covers the sharded path even there.
 """
 import json
+import os
 import subprocess
 import sys
 from pathlib import Path
@@ -398,43 +399,62 @@ class TestLRUCaches:
 # Persistent compile cache
 # ---------------------------------------------------------------------------
 
+#: a fresh interpreter that imports repro.sim, runs one sweep and prints
+#: (cache directory JAX uses, cache hits, result) as JSON.
+_CACHE_PROBE = """
+import json, sys
+sys.path.insert(0, r'%s')
+import jax
+hits = []
+jax.monitoring.register_event_listener(
+    lambda name, **_: hits.append(1)
+    if name == '/jax/compilation_cache/cache_hits' else None)
+from repro.sim import evaluate_grid, mu_rho_grid
+r = evaluate_grid(mu_rho_grid([60, 300], [5.5]))
+print(json.dumps([jax.config.jax_compilation_cache_dir, len(hits),
+                  float(r.energy_ratio[0, 0])]))
+""" % (ROOT / "src")
+
+
+def _cache_probe(**env_extra):
+    from repro.sim import cache as c
+    env = {k: v for k, v in os.environ.items() if k != c.ENV_VAR}
+    env.update(env_extra, JAX_PLATFORMS="cpu")
+    p = subprocess.run([sys.executable, "-c", _CACHE_PROBE],
+                       capture_output=True, text=True, timeout=600, env=env)
+    assert p.returncode == 0, p.stderr[-2000:]
+    return json.loads(p.stdout.strip().splitlines()[-1])
+
+
 class TestCompileCache:
     def test_cache_helper_writes_and_reuses_entries(self, tmp_path):
-        """Two fresh interpreters against one cache dir: the first
-        populates it, the second must still produce identical results
-        (and the dir must hold serialized executables)."""
-        script = (
-            "import sys; sys.path.insert(0, r'%s')\n"
-            "from repro.sim import enable_compile_cache, evaluate_grid, "
-            "mu_rho_grid\n"
-            "enable_compile_cache(r'%s')\n"
-            "r = evaluate_grid(mu_rho_grid([60, 300], [5.5]))\n"
-            "print(float(r.energy_ratio[0, 0]))"
-        ) % (ROOT / "src", tmp_path)
-        outs = []
-        for _ in range(2):
-            p = subprocess.run([sys.executable, "-c", script],
-                               capture_output=True, text=True, timeout=600)
-            assert p.returncode == 0, p.stderr[-2000:]
-            outs.append(p.stdout.strip().splitlines()[-1])
-        assert outs[0] == outs[1]
-        assert any(f.name.endswith("-cache") or "jit_" in f.name
-                   for f in tmp_path.iterdir()), list(tmp_path.iterdir())
+        """With ``$JAX_COMPILATION_CACHE_DIR`` set, two fresh interpreters
+        share that directory: the first writes entries there, the second
+        hits them and gets the identical result."""
+        env = dict(JAX_COMPILATION_CACHE_DIR=str(tmp_path),
+                   JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0")
+        first = _cache_probe(**env)
+        second = _cache_probe(**env)
+        assert first[0] == second[0] == str(tmp_path)
+        assert any(tmp_path.iterdir())
+        assert second[1] > 0
+        assert first[2] == second[2]
 
     def test_env_var_autoenable(self, tmp_path, monkeypatch):
+        """A directory placed from outside is JAX's to read: the helper
+        reports it and sets no directory of its own."""
         from repro.sim import cache as c
+        before = jax.config.jax_compilation_cache_dir
         monkeypatch.setenv(c.ENV_VAR, str(tmp_path / "cc"))
-        assert c.maybe_enable_from_env() == str(tmp_path / "cc")
-        monkeypatch.delenv(c.ENV_VAR)
-        # restore whatever was active before (idempotent helper)
-        if c.active_cache_dir():
-            pass
+        assert c.enable_compile_cache() == str(tmp_path / "cc")
+        assert jax.config.jax_compilation_cache_dir == before
 
-    def test_unusable_cache_dir_warns_instead_of_crashing(self, monkeypatch):
+    def test_default_dir_is_in_checkout(self):
+        """Without the variable, every process uses the one fixed
+        directory inside the checkout (and nothing under the home)."""
         from repro.sim import cache as c
-        monkeypatch.setenv(c.ENV_VAR, "/proc/definitely/not/writable")
-        with pytest.warns(RuntimeWarning, match="unusable"):
-            assert c.maybe_enable_from_env() is None
+        assert c.CHECKOUT_DIR == ROOT / ".jax_cache"
+        assert _cache_probe()[0] == str(c.CHECKOUT_DIR)
 
 
 class TestEnvKnobGuards:

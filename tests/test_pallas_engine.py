@@ -174,6 +174,32 @@ class TestPrecisionKnobs:
                                           np.asarray(getattr(b, f)),
                                           err_msg=f)
 
+    def test_missing_backend_raises(self):
+        """A backend with no devices here is an error, never a silent
+        run on another device."""
+        g = mu_rho_grid(mus=(800.0, 2000.0), rhos=(0.5, 1.0))
+        with pytest.raises(RuntimeError):
+            evaluate_grid(g, dispatch=DispatchConfig(backend="tpu"))
+
+    def test_interpret_mode_only_on_cpu(self, monkeypatch):
+        """Pallas interprets on the CPU backend, lowers on a TPU, and
+        refuses any other backend."""
+        import jax
+        from repro.kernels.ops import interpret_mode
+        assert interpret_mode() is True
+        assert interpret_mode(False) is False
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        assert interpret_mode() is False
+        monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+        with pytest.raises(RuntimeError, match="no Pallas lowering"):
+            interpret_mode()
+
+    def test_unknown_device_kind_has_no_peaks(self):
+        from benchmarks.roofline import PLAN_BACKEND, resolve_peaks
+        assert resolve_peaks(PLAN_BACKEND).link_bw == 1600e9 / 8
+        with pytest.raises(ValueError, match="no peaks"):
+            resolve_peaks("TPU v6 lite")
+
     def test_resolution_order(self, monkeypatch):
         # explicit argument beats everything
         cfg = DispatchConfig(precision=F64)
