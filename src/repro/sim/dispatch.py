@@ -506,31 +506,37 @@ def run(key, build, args, in_axes: Sequence[Optional[int]], out_axes,
         flat_axes = None
         bufs = None
         for start, stop, padded in plan:
-            chunk_args = [
-                const[i] if ax is None
-                else _slice_pad(args[i], ax, start, stop, padded)
-                for i, ax in enumerate(in_axes)]
-            out = runner(*chunk_args)
-            leaves, tdef = jax.tree.flatten(out)
-            if treedef is None:
-                treedef = tdef
-                flat_axes = (jax.tree.leaves(out_axes)
-                             if not isinstance(out_axes, int)
-                             else [out_axes] * len(leaves))
-                if len(flat_axes) == 1 and len(leaves) > 1:
-                    flat_axes = flat_axes * len(leaves)
-                if len(plan) == 1 and padded == size:
-                    return tdef.unflatten([np.asarray(v) for v in leaves])
-                bufs = []
-                for leaf, ax in zip(leaves, flat_axes):
-                    shp = list(np.shape(leaf))
-                    shp[ax] = size
-                    bufs.append(np.empty(shp, dtype=np.asarray(leaf).dtype))
-            for leaf, ax, buf in zip(leaves, flat_axes, bufs):
-                arr = np.asarray(leaf)
-                sel = [slice(None)] * arr.ndim
-                sel[ax] = slice(0, stop - start)      # drop padding lanes
-                dst = [slice(None)] * arr.ndim
-                dst[ax] = slice(start, stop)
-                buf[tuple(dst)] = arr[tuple(sel)]
+            with jax.profiler.TraceAnnotation("repro.dispatch.launch"):
+                chunk_args = [
+                    const[i] if ax is None
+                    else _slice_pad(args[i], ax, start, stop, padded)
+                    for i, ax in enumerate(in_axes)]
+                out = runner(*chunk_args)
+            # np.asarray waits for the program to finish, so this span
+            # holds its device time as well as the copy-back.
+            with jax.profiler.TraceAnnotation("repro.dispatch.fetch"):
+                leaves, tdef = jax.tree.flatten(out)
+                if treedef is None:
+                    treedef = tdef
+                    flat_axes = (jax.tree.leaves(out_axes)
+                                 if not isinstance(out_axes, int)
+                                 else [out_axes] * len(leaves))
+                    if len(flat_axes) == 1 and len(leaves) > 1:
+                        flat_axes = flat_axes * len(leaves)
+                    if len(plan) == 1 and padded == size:
+                        return tdef.unflatten([np.asarray(v)
+                                               for v in leaves])
+                    bufs = []
+                    for leaf, ax in zip(leaves, flat_axes):
+                        shp = list(np.shape(leaf))
+                        shp[ax] = size
+                        bufs.append(np.empty(
+                            shp, dtype=np.asarray(leaf).dtype))
+                for leaf, ax, buf in zip(leaves, flat_axes, bufs):
+                    arr = np.asarray(leaf)
+                    sel = [slice(None)] * arr.ndim
+                    sel[ax] = slice(0, stop - start)  # drop padding lanes
+                    dst = [slice(None)] * arr.ndim
+                    dst[ax] = slice(start, stop)
+                    buf[tuple(dst)] = arr[tuple(sel)]
     return treedef.unflatten(bufs)

@@ -93,6 +93,7 @@ cache is LRU-bounded like the rest.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 import os
 from typing import Optional
@@ -111,6 +112,11 @@ from . import precision as _precision
 from .scenarios import MultilevelParamGrid, ParamGrid
 
 COMPUTE, CHECKPOINT = 0, 1
+
+#: ``jax.named_scope`` names of the two stages of a Monte-Carlo program:
+#: the traced failure sampler and the trajectory kernel (scan or Pallas),
+#: whichever implements them.  They mark the ops' HLO metadata only.
+SAMPLE_SCOPE, SCAN_SCOPE = "mc.sample", "mc.scan"
 
 #: work-completion slack, identical to the scalar simulator's epsilon.
 _EPS = 1e-12
@@ -434,15 +440,17 @@ def _grid_fn(n_steps: int, kind: str, policy=None):
         pol = policy if policy is not None else _precision.F64
 
         def run_grid(T, C, R, D, omega, T_base, gaps):
-            return _es.event_sweep(T, C, R, D, omega, T_base, gaps,
-                                   n_steps=n_steps, dtype=pol.dtype,
-                                   compensated=pol.compensated)
+            with jax.named_scope(SCAN_SCOPE):
+                return _es.event_sweep(T, C, R, D, omega, T_base, gaps,
+                                       n_steps=n_steps, dtype=pol.dtype,
+                                       compensated=pol.compensated)
         return run_grid
     kernel = _KERNELS[kind]
 
     def run_grid(T, C, R, D, omega, T_base, gaps):
         def one(t, c, r, d, o, tb, g):
-            return kernel(t, c, r, d, o, tb, g, n_steps)
+            with jax.named_scope(SCAN_SCOPE):
+                return kernel(t, c, r, d, o, tb, g, n_steps)
         over_trials = jax.vmap(one, in_axes=(None,) * 6 + (0,))
         over_grid = jax.vmap(over_trials, in_axes=(0,) * 6 + (0,))
         return over_grid(T, C, R, D, omega, T_base, gaps)
@@ -460,8 +468,11 @@ def _cand_fn(n_steps: int, kind: str, policy=None):
 
     if kind == "pallas":
         def run_cands(T2, C, R, D, omega, T_base, gaps):
-            return lax.map(
-                lambda t: run_grid(t, C, R, D, omega, T_base, gaps), T2)
+            # The candidate loop itself is scan work: its op carries the
+            # scope as well as the kernel calls inside it.
+            with jax.named_scope(SCAN_SCOPE):
+                return lax.map(
+                    lambda t: run_grid(t, C, R, D, omega, T_base, gaps), T2)
         return run_cands
 
     def run_cands(T2, C, R, D, omega, T_base, gaps):
@@ -743,20 +754,24 @@ def _sampled_build(proc_fn, cap_sample: int, cap_used: int,
             # schedule out before the kernel's cast and relayout.  Fused
             # into them (under vmap), the emulated-f64 sampler takes the
             # TPU compiler minutes instead of seconds.
-            gaps = lax.map(lambda a: sample_point(*a),
-                           (mean, idx) + tuple(params))
+            with jax.named_scope(SAMPLE_SCOPE):
+                gaps = lax.map(lambda a: sample_point(*a),
+                               (mean, idx) + tuple(params))
             return run_grid(T, C, R, D, omega, Tb, gaps[:, :, :cap_used])
         return build
     kernel = _KERNELS[kind]
 
     def build(T, C, R, D, omega, Tb, mean, idx, t_idx, key, *params):
         def per_point(t, c, r, d, o, tb, m, i, *pp):
-            kp = jax.random.fold_in(key, i)
+            with jax.named_scope(SAMPLE_SCOPE):
+                kp = jax.random.fold_in(key, i)
 
             def per_trial(ti):
-                kt = jax.random.fold_in(kp, ti)
-                g = proc_fn(kt, (cap_sample,), m, pp)
-                return kernel(t, c, r, d, o, tb, g[:cap_used], n_steps)
+                with jax.named_scope(SAMPLE_SCOPE):
+                    g = proc_fn(jax.random.fold_in(kp, ti), (cap_sample,),
+                                m, pp)
+                with jax.named_scope(SCAN_SCOPE):
+                    return kernel(t, c, r, d, o, tb, g[:cap_used], n_steps)
             return jax.vmap(per_trial)(t_idx)
         return jax.vmap(per_point)(T, C, R, D, omega, Tb, mean, idx,
                                    *params)
@@ -814,6 +829,8 @@ def _assemble_batch(out: dict, grid: ParamGrid, n_trials: int,
         gaps_exhausted=out["gaps_exhausted"].reshape(shp))
 
 
+@functools.partial(jax.profiler.annotate_function,
+                   name="repro.mc.trajectories")
 def simulate_trajectories(T, grid: ParamGrid, T_base: float = 1.0,
                           n_trials: int = 200, seed: int = 0,
                           gaps: Optional[np.ndarray] = None,
@@ -977,10 +994,12 @@ def _cand_sampled_build(proc_fn, cap_sample: int, n_steps: int, kind: str,
                 return proc_fn(jax.random.fold_in(kp, ti), (cap_sample,),
                                m, pp)
             return jax.vmap(sample_trial)(t_idx)
-        gaps = jax.vmap(sample_point)(mean, idx, *params)
+        with jax.named_scope(SAMPLE_SCOPE):
+            gaps = jax.vmap(sample_point)(mean, idx, *params)
         if kind == "pallas":
-            return lax.map(
-                lambda t: run_grid(t, C, R, D, omega, Tb, gaps), T2)
+            with jax.named_scope(SCAN_SCOPE):
+                return lax.map(
+                    lambda t: run_grid(t, C, R, D, omega, Tb, gaps), T2)
         return jax.vmap(run_grid, in_axes=(0,) + (None,) * 6)(
             T2, C, R, D, omega, Tb, gaps)
     return build
@@ -993,6 +1012,8 @@ def _cand_axis(M: int, B: int) -> str:
     return "cand" if B == 1 and M > 1 else "grid"
 
 
+@functools.partial(jax.profiler.annotate_function,
+                   name="repro.mc.candidates")
 def simulate_candidates(T_cand, grid: ParamGrid, T_base: float = 1.0,
                         n_trials: int = 200, seed: int = 0,
                         gaps: Optional[np.ndarray] = None,

@@ -15,6 +15,7 @@ argmin — falls back to the numeric result elementwise.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Callable, Optional, Sequence
 
@@ -901,6 +902,8 @@ def _mc_eval(T_cand, flat: ParamGrid, T_base, gaps, n_steps=None,
             se(tb.wall_time), se(tb.energy))
 
 
+@functools.partial(jax.profiler.annotate_function,
+                   name="repro.robust.solve")
 def evaluate_robustness_grid(grid: ParamGrid, process,
                              T_base: Optional[float] = None,
                              n_trials: int = 160, seed: int = 0,
@@ -922,19 +925,19 @@ def evaluate_robustness_grid(grid: ParamGrid, process,
     from . import engine as _engine
     process = as_process(process)
     engine_kind = _engine.resolve_engine_kind(engine_kind)
-    res = evaluate_grid(grid, T_base=1.0, dispatch=dispatch)
-    if not res.valid.all():
-        raise ValueError("robustness sweep: grid contains degenerate points "
-                         "(no valid period); filter them first")
     flat = grid.ravel()
     B = flat.size
+    with jax.profiler.TraceAnnotation("repro.robust.closed_forms"):
+        res = evaluate_grid(grid, T_base=1.0, dispatch=dispatch)
+        if not res.valid.all():
+            raise ValueError("robustness sweep: grid contains degenerate "
+                             "points (no valid period); filter them first")
+        Tt = np.asarray(res.T_time, dtype=np.float64).ravel()
+        Te = np.asarray(res.T_energy, dtype=np.float64).ravel()
+        Ty = np.asarray(res.T_young, dtype=np.float64).ravel()
+        Td = np.asarray(res.T_daly, dtype=np.float64).ravel()
+        lo0, hi0 = flat.period_bounds()
 
-    Tt = np.asarray(res.T_time, dtype=np.float64).ravel()
-    Te = np.asarray(res.T_energy, dtype=np.float64).ravel()
-    Ty = np.asarray(res.T_young, dtype=np.float64).ravel()
-    Td = np.asarray(res.T_daly, dtype=np.float64).ravel()
-
-    lo0, hi0 = flat.period_bounds()
     # Search well clear of the bracket edges, where E[T_final] (and with it
     # the scan/schedule budgets) diverges; the optimum sits near the
     # exponential T* for every renewal process with the same mean.
@@ -951,11 +954,12 @@ def evaluate_robustness_grid(grid: ParamGrid, process,
     n_steps = (None if engine_kind in _engine._EVENT_LIKE else
                _engine.default_step_budget(probes, flat, T_base,
                                            process=process))
-    gaps = _engine.presample_gaps(flat, n_trials, cap, seed=seed,
-                                  process=process)
-    with enable_x64():
-        # device-resident once, reused below
-        gaps = jnp.asarray(gaps, dtype=jnp.float64)
+    with jax.profiler.TraceAnnotation("repro.robust.schedule"):
+        gaps = _engine.presample_gaps(flat, n_trials, cap, seed=seed,
+                                      process=process)
+        with enable_x64():
+            # device-resident once, reused below
+            gaps = jnp.asarray(gaps, dtype=jnp.float64)
 
     # Coarse-to-fine localization of both argmins (batched over the grid).
     frac = np.linspace(0.0, 1.0, n_candidates)[:, None]
