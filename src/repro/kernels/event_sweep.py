@@ -128,8 +128,8 @@ def _event_kernel(T_ref, C_ref, R_ref, D_ref, O_ref, TB_ref, gaps_ref,
             c_wall, c_comm, c_work, c_io, c_down = carry[10:]
 
         # Uniform slab read: active lanes have n_fail == i (see module
-        # docstring), so one dynamic index on the capacity axis replaces
-        # the scan kernel's per-lane gather; past-the-schedule reads are
+        # docstring), so one dynamic index on the capacity axis serves
+        # every lane, as in the scan kernel; past-the-schedule reads are
         # inf == "no more failures", flagging exhaustion.
         in_range = i < n_gaps
         slab = gaps_ref[jnp.clip(i - lo, i32(0), i32(fb - 1))]

@@ -300,9 +300,20 @@ def _run_one_event(T, C, R, D, omega, T_base, gaps, n_steps):
     The ``eps`` in ``j`` reproduces the step kernel's completion slack
     (``live >= T_base - eps``): finishing exactly at a checkpoint boundary
     does NOT count that final checkpoint.
+
+    Step ``i`` reads gap ``i`` by loop index: a lane still running at step
+    ``i`` has ``n_fail == i`` (each step either ends it, frozen from then
+    on, or counts one failure), and a finished lane discards what it
+    reads.  So the schedule reaches the scan as ``xs``, padded with
+    ``inf`` (or cut) to ``n_steps``; under the vmaps the scan moves it to
+    a capacity-major ``(n_steps, lanes)`` slab once, and each step reads
+    one contiguous row instead of gathering from the whole schedule.
     """
     f64 = gaps.dtype
     n_gaps = gaps.shape[0]
+    slab = jnp.pad(gaps, (0, max(n_steps - n_gaps, 0)),
+                   constant_values=jnp.inf)[:n_steps]
+    in_ranges = jnp.arange(n_steps, dtype=jnp.int32) < n_gaps
     Tc = T - C                          # compute-segment length
     w = T - (1.0 - omega) * C           # work committed per full period
     omega_safe = jnp.where(omega > 0.0, omega, 1.0)
@@ -317,17 +328,15 @@ def _run_one_event(T, C, R, D, omega, T_base, gaps, n_steps):
             jnp.zeros((), jnp.bool_),   # used_inf (schedule ran dry)
             jnp.zeros((), jnp.bool_))   # done
 
-    def step(carry, _):
+    def step(carry, x):
         (wall, committed, work_exec, io_time, down_time,
          n_fail, n_ckpt, used_inf, done) = carry
 
         # One gap per inter-failure stretch, exactly like the step kernel's
         # one-draw-per-stretch accounting (the initial draw + one per
         # failure); reading past the schedule yields inf == "no more
-        # failures" and flags exhaustion.
-        in_range = n_fail < n_gaps
-        g = jnp.where(in_range, gaps[jnp.minimum(n_fail, n_gaps - 1)],
-                      jnp.inf)
+        # failures" and flags exhaustion.  Gap i at step i (docstring).
+        g, in_range = x
 
         # ---- closed-form completion time from this segment start ----
         rem = T_base - committed
@@ -375,7 +384,7 @@ def _run_one_event(T, C, R, D, omega, T_base, gaps, n_steps):
         keep = lambda old, upd: jnp.where(done, old, upd)
         return tuple(keep(o, u) for o, u in zip(carry, new)), None
 
-    final, _ = lax.scan(step, init, None, length=n_steps)
+    final, _ = lax.scan(step, init, (slab, in_ranges), length=n_steps)
     (wall, _committed, work_exec, io_time, down_time,
      n_fail, n_ckpt, used_inf, done) = final
     return {"wall_time": wall, "work_executed": work_exec,
@@ -686,6 +695,13 @@ def _as_f64_gaps(gaps):
     return np.asarray(gaps, dtype=np.float64)
 
 
+def _slab_slots(kind: str, n_steps: int) -> int:
+    """Gap slots per trajectory that the event scan's capacity-major slab
+    adds next to the schedule (:func:`_run_one_event`); the other kinds
+    read the schedule where it lies."""
+    return int(n_steps) if kind == "event" else 0
+
+
 def _trial_chunk(n_trials: int, capacity: int, ndev: int, cfg) -> int:
     """Trials per dispatch: all of them, unless even one grid chunk row
     per device at the full trial count would blow the memory budget —
@@ -706,9 +722,10 @@ def _dispatch_explicit(T_arr, flat: ParamGrid, Tb_arr, gaps, n_steps: int,
     memory-bounded blocks; returns numpy ``(B, n_trials)`` per key."""
     B = flat.size
     gaps = _as_f64_gaps(gaps)
-    n_trials, cap = int(gaps.shape[-2]), int(gaps.shape[-1])
+    n_trials = int(gaps.shape[-2])
+    slots = int(gaps.shape[-1]) + _slab_slots(kind, n_steps)
     ndev = _dispatch.effective_devices(cfg)
-    tc = _trial_chunk(n_trials, cap, ndev, cfg)
+    tc = _trial_chunk(n_trials, slots, ndev, cfg)
     parts = []
     for t0 in range(0, n_trials, tc):
         g = gaps[:, t0:t0 + tc, :]
@@ -717,7 +734,7 @@ def _dispatch_explicit(T_arr, flat: ParamGrid, Tb_arr, gaps, n_steps: int,
             build=_grid_fn(int(n_steps), kind, policy),
             args=(T_arr, flat.C, flat.R, flat.D, flat.omega, Tb_arr, g),
             in_axes=(0,) * 7, out_axes=0, size=B,
-            per_point_bytes=8 * min(tc, n_trials) * (cap + 32),
+            per_point_bytes=8 * min(tc, n_trials) * (slots + 32),
             config=cfg))
     if len(parts) == 1:
         return parts[0]
@@ -934,7 +951,6 @@ def simulate_trajectories(T, grid: ParamGrid, T_base: float = 1.0,
         g_full = _bulk_schedule(flat, n_trials, cap_sample, seed, process)
 
     ndev = _dispatch.effective_devices(cfg)
-    tc = _trial_chunk(n_trials, cap_sample, ndev, cfg)
     acc: dict = {}
     for b in np.unique(budgets):
         idx = np.nonzero(budgets == b)[0]
@@ -947,6 +963,8 @@ def simulate_trajectories(T, grid: ParamGrid, T_base: float = 1.0,
                                      int(b), engine_kind, cfg, policy=pol)
             _scatter(acc, out, flat.size, n_trials, idx, slice(None))
             continue
+        slots = cap_sample + _slab_slots(engine_kind, b)
+        tc = _trial_chunk(n_trials, slots, ndev, cfg)
         for t0 in range(0, n_trials, tc):
             t_idx = np.arange(t0, min(t0 + tc, n_trials), dtype=np.uint32)
             out = _dispatch.run(
@@ -959,7 +977,7 @@ def simulate_trajectories(T, grid: ParamGrid, T_base: float = 1.0,
                       key) + tuple(p[idx] for p in params_b),
                 in_axes=(0,) * 8 + (None, None) + (0,) * len(params_b),
                 out_axes=0, size=len(idx),
-                per_point_bytes=8 * len(t_idx) * (cap_sample + 32),
+                per_point_bytes=8 * len(t_idx) * (slots + 32),
                 config=cfg)
             _scatter(acc, out, flat.size, n_trials, idx,
                      slice(t0, t0 + len(t_idx)))
@@ -1076,7 +1094,8 @@ def simulate_candidates(T_cand, grid: ParamGrid, T_base: float = 1.0,
                  _kind_token(engine_kind, pol), len(params_b)),
                 _cand_sampled_build(proc_fn, cap, int(ns), engine_kind,
                                     policy=pol),
-                T2, flat, Tb_arr, axis, cfg, n_trials, cap,
+                T2, flat, Tb_arr, axis, cfg, n_trials,
+                cap + _slab_slots(engine_kind, ns),
                 sampler_args=(mean_arr, idx_all, key, params_b))
             return _assemble_batch(out, grid, n_trials, lead=(M,))
 
@@ -1091,13 +1110,13 @@ def simulate_candidates(T_cand, grid: ParamGrid, T_base: float = 1.0,
     out = _dispatch_cands(
         ("cand_explicit", int(n_steps), _kind_token(engine_kind, pol)),
         _cand_fn(int(n_steps), engine_kind, policy=pol),
-        T2, flat, Tb_arr, axis, cfg, n_trials, int(gaps.shape[-1]),
-        gaps=gaps)
+        T2, flat, Tb_arr, axis, cfg, n_trials,
+        int(gaps.shape[-1]) + _slab_slots(engine_kind, n_steps), gaps=gaps)
     return _assemble_batch(out, grid, n_trials, lead=(M,))
 
 
 def _dispatch_cands(key, build, T2, flat: ParamGrid, Tb_arr, axis: str,
-                    cfg, n_trials: int, cap: int, gaps=None,
+                    cfg, n_trials: int, slots: int, gaps=None,
                     sampler_args=None) -> dict:
     """Route a candidate-vmap runner through the dispatcher.
 
@@ -1107,7 +1126,8 @@ def _dispatch_cands(key, build, T2, flat: ParamGrid, Tb_arr, axis: str,
     solver shape).  The trials axis streams in memory-bounded blocks on
     both schedule paths (explicit schedules are sliced; auto-sampled
     blocks re-derive their per-(point, trial) folded keys, so blocking
-    is bit-exact).
+    is bit-exact).  ``slots`` is the gap slots a trajectory holds on
+    device: its schedule's capacity plus :func:`_slab_slots`.
     """
     M, B = T2.shape
     ndev = _dispatch.effective_devices(cfg)
@@ -1116,12 +1136,12 @@ def _dispatch_cands(key, build, T2, flat: ParamGrid, Tb_arr, axis: str,
     if not sampled:
         gaps = _as_f64_gaps(gaps)
     # Trials stream in memory-bounded blocks on BOTH schedule paths.  On
-    # the candidate axis the (B, trials-block, cap) schedule is
+    # the candidate axis the (B, trials-block, slots) schedule is
     # replicated per device (ndev-independent, hence the 1), so the
     # block length is what bounds it; on the grid axis each point owns
     # its schedule slice plus M candidates' worth of live carries.
     tc = _trial_chunk(n_trials,
-                      B * cap if axis == "cand" else cap + 32 * M,
+                      B * slots if axis == "cand" else slots + 32 * M,
                       1 if axis == "cand" else ndev, cfg)
     parts = []
     for t0 in range(0, n_trials, tc):
@@ -1147,7 +1167,7 @@ def _dispatch_cands(key, build, T2, flat: ParamGrid, Tb_arr, axis: str,
             out = _dispatch.run(
                 key=key + ("grid",), build=build, args=args,
                 in_axes=grid_axes, out_axes=1, size=B,
-                per_point_bytes=8 * (t1 - t0) * (cap + 32 * M),
+                per_point_bytes=8 * (t1 - t0) * (slots + 32 * M),
                 config=cfg)
         parts.append(out)
     if len(parts) == 1:

@@ -341,3 +341,239 @@ class TestEventEngineStatistics:
         got = float(tb.wall_time.mean())
         se = float(tb.wall_time.std(ddof=1) / math.sqrt(600))
         assert abs(got - want) < 4.0 * se + 0.01 * want
+
+
+def _gather_event(T, C, R, D, omega, T_base, gaps, n_steps):
+    """The event kernel with its gap read in gather form: each lane reads
+    ``gaps[n_fail]``, a per-lane index.  The same step arithmetic as
+    ``engine._run_one_event``, expression for expression."""
+    import jax.numpy as jnp
+    from jax import lax
+    from repro.sim.engine import _EPS
+
+    f64 = gaps.dtype
+    n_gaps = gaps.shape[0]
+    Tc = T - C
+    w = T - (1.0 - omega) * C
+    omega_safe = jnp.where(omega > 0.0, omega, 1.0)
+    init = ((jnp.zeros((), f64),) * 5 + (jnp.zeros((), jnp.int32),) * 2
+            + (jnp.zeros((), jnp.bool_),) * 2)
+
+    def step(carry, _):
+        (wall, committed, work_exec, io_time, down_time,
+         n_fail, n_ckpt, used_inf, done) = carry
+        in_range = n_fail < n_gaps
+        g = jnp.where(in_range, gaps[jnp.minimum(n_fail, n_gaps - 1)],
+                      jnp.inf)
+        rem = T_base - committed
+        j = jnp.maximum(jnp.floor((rem - _EPS) / w), 0.0)
+        r = rem - j * w
+        rr = r - Tc
+        t_in = jnp.where(rr > 0.0, Tc + rr / omega_safe, r)
+        t_fin = j * T + t_in
+        complete = t_fin < g
+        wall_a = wall + t_fin
+        work_a = work_exec + rem
+        io_a = io_time + j * C + jnp.maximum(rr, 0.0) / omega_safe
+        s = jnp.where(jnp.isfinite(g), g, 0.0)
+        k = jnp.floor(s / T)
+        k = jnp.where((k > 0.0) & (k * T >= s), k - 1.0, k)
+        u = s - k * T
+        uc = u - Tc
+        work_b = work_exec + k * w + jnp.where(uc > 0.0,
+                                               Tc + omega * uc, u)
+        io_b = io_time + k * C + jnp.maximum(uc, 0.0) + R
+        wall_b = (wall + s) + D + R
+        committed_b = jnp.where(k >= 1.0,
+                                committed + (k - 1.0) * w + Tc, committed)
+
+        def sel(a_val, b_val):
+            return jnp.where(complete, a_val, b_val)
+
+        new = (sel(wall_a, wall_b),
+               sel(committed, committed_b),
+               sel(work_a, work_b),
+               sel(io_a, io_b),
+               sel(down_time, down_time + D),
+               sel(n_fail, n_fail + 1).astype(jnp.int32),
+               (n_ckpt + sel(j, k).astype(jnp.int32)).astype(jnp.int32),
+               jnp.logical_or(used_inf, ~in_range),
+               jnp.logical_or(done, complete))
+        keep = lambda old, upd: jnp.where(done, old, upd)
+        return tuple(keep(o, u) for o, u in zip(carry, new)), None
+
+    final, _ = lax.scan(step, init, None, length=n_steps)
+    (wall, _committed, work_exec, io_time, down_time,
+     n_fail, n_ckpt, used_inf, done) = final
+    return {"wall_time": wall, "work_executed": work_exec,
+            "io_time": io_time, "down_time": down_time,
+            "n_failures": n_fail, "n_checkpoints": n_ckpt,
+            "truncated": ~done, "gaps_exhausted": used_inf}
+
+
+@pytest.fixture
+def gather_read(monkeypatch):
+    """Run a call once through the engine as it is and once with the
+    gather-form kernel in its place, on runners compiled for each."""
+    from repro.sim import dispatch, engine
+
+    def both(call):
+        dispatch._RUNNERS.clear()
+        slab = call()
+        with monkeypatch.context() as m:
+            m.setitem(engine._KERNELS, "event", _gather_event)
+            dispatch._RUNNERS.clear()
+            gather = call()
+        dispatch._RUNNERS.clear()
+        return slab, gather
+    return both
+
+
+def _mixed_mu_grid():
+    base = ParamGrid.from_params(CK, PW)
+    mus = np.array([60.0, 400.0, 3000.0])
+    return ParamGrid(**{f: (mus if f == "mu" else np.broadcast_to(v, (3,)))
+                        for f, v in base.fields().items()})
+
+
+#: (schedule, capacity, n_steps) per path: the sampled builds at their own
+#: budgets; explicit schedules cut short (n_steps below the capacity),
+#: padded by one step (the default capacity + 1), and padded far past the
+#: capacity so that lanes run the schedule dry.
+_SLAB_PATHS = {
+    "sampled": (None, None, None),
+    "explicit_cut": ("explicit", 64, 4),
+    "explicit_default": ("explicit", 64, None),
+    "explicit_dry": ("explicit", 12, 300),
+    "cand_sampled": (None, None, None),
+    "cand_explicit_dry": ("explicit", 12, 300),
+}
+
+
+class TestSlabRead:
+    """The event scan reads gap ``i`` at step ``i`` from a capacity-major
+    slab; the gather-form read of ``gaps[n_fail]`` gives the same bits."""
+
+    @pytest.mark.parametrize("path", sorted(_SLAB_PATHS))
+    @pytest.mark.parametrize("proc", PROCESSES, ids=lambda p: p.name)
+    def test_bit_identical_to_gather_read(self, gather_read, proc, path):
+        grid = _mixed_mu_grid()
+        sched, cap, n_steps = _SLAB_PATHS[path]
+        kw = dict(T_base=4000.0, n_steps=n_steps, engine_kind="event")
+        if sched is None:
+            kw.update(n_trials=6, seed=2**40 + 9, process=proc)
+        else:
+            kw["gaps"] = presample_gaps(grid, 6, cap, seed=4, process=proc)
+        if path.startswith("cand"):
+            Ts = np.array([[40.0, 60.0, 90.0], [55.0, 80.0, 130.0]])
+            slab, gather = gather_read(
+                lambda: simulate_candidates(Ts, grid, **kw))
+        else:
+            slab, gather = gather_read(
+                lambda: simulate_trajectories(60.0, grid, **kw))
+        for name, a in _fields(gather).items():
+            np.testing.assert_array_equal(getattr(slab, name), a,
+                                          err_msg=name)
+        if path == "explicit_cut":
+            assert slab.truncated.any()
+        if path.endswith("dry"):
+            assert slab.gaps_exhausted.any() and not slab.gaps_exhausted.all()
+
+    @pytest.mark.parametrize("n_steps", [0, 1, 33, 40])
+    def test_kernel_at_any_step_count(self, n_steps):
+        """The kernel itself, vmapped over lanes: no step at all (every
+        lane truncated, nothing accumulated), one step, the capacity + 1,
+        and past it."""
+        import jax
+        from jax import enable_x64
+        from repro.sim.engine import _KERNELS
+
+        rng = np.random.default_rng(7)
+        gaps = rng.exponential(150.0, size=(16, 32))
+        T = np.linspace(30.0, 120.0, 16)
+        args = (T, 10.0, 8.0, 1.0, 0.5, 900.0)
+        axes = (0,) + (None,) * 5 + (0,)
+        with enable_x64():
+            slab = jax.jit(jax.vmap(
+                lambda *a: _KERNELS["event"](*a, n_steps), axes))(
+                    *args, gaps)
+            gather = jax.jit(jax.vmap(
+                lambda *a: _gather_event(*a, n_steps), axes))(*args, gaps)
+        for name, a in gather.items():
+            np.testing.assert_array_equal(np.asarray(slab[name]),
+                                          np.asarray(a), err_msg=name)
+        if n_steps == 0:
+            assert np.asarray(slab["truncated"]).all()
+            assert not np.asarray(slab["wall_time"]).any()
+        if n_steps == 40:
+            assert not np.asarray(slab["truncated"]).any()
+
+
+def _loop_ops(text: str, op: str) -> int:
+    """How many ``op`` the ``stablehlo.while`` loops of a lowered module
+    run: the loops' regions, and the bodies of the functions they call."""
+    import re
+
+    funcs = {}
+    heads = list(re.finditer(r"func\.func (?:\w+ )?@([\w$.-]+)", text))
+    for h, nxt in zip(heads, heads[1:] + [None]):
+        funcs[h.group(1)] = text[h.end():nxt.start() if nxt else len(text)]
+
+    def region(start: int) -> str:
+        depth, blocks, i = 0, 0, text.index("{", start)
+        for i in range(i, len(text)):
+            depth += {"{": 1, "}": -1}.get(text[i], 0)
+            if depth == 0 and text[i] == "}":
+                blocks += 1
+                if blocks == 2:              # the cond and the do block
+                    return text[start:i]
+        return text[start:]
+
+    def count(body: str, seen: frozenset) -> int:
+        n = body.count(op)
+        for name in re.findall(r"call @([\w$.-]+)", body):
+            if name in funcs and name not in seen:
+                n += count(funcs[name], seen | {name})
+        return n
+
+    loops = [m.start() for m in re.finditer(r"stablehlo\.while", text)]
+    assert loops, "no loop in the lowered program"
+    return sum(count(region(s), frozenset()) for s in loops)
+
+
+class TestSlabLowering:
+    """No gather runs inside the event scan's loop: the schedule is laid
+    out capacity-major once, before it, and each step slices one row."""
+
+    @staticmethod
+    def _lowered(runner: str) -> str:
+        import jax
+        from jax import enable_x64
+        from repro.sim import engine
+
+        B, trials, cap = 2, 3, 128
+        grid_args = (np.full(B, 10.0), np.full(B, 8.0), np.ones(B),
+                     np.full(B, 0.5), np.full(B, 900.0))
+        T = np.full(B, 60.0)
+        gaps = np.full((B, trials, cap), 100.0)
+        if runner == "_grid_fn":
+            f, args = engine._grid_fn(cap + 1, "event"), (T,)
+        else:
+            f, args = engine._cand_fn(cap + 1, "event"), (
+                np.stack([T, 2 * T]),)
+        with enable_x64():
+            return jax.jit(f).lower(*args, *grid_args, gaps).as_text()
+
+    @pytest.mark.parametrize("runner", ["_grid_fn", "_cand_fn"])
+    def test_no_gather_in_the_loop(self, runner):
+        text = self._lowered(runner)
+        assert _loop_ops(text, "stablehlo.gather") == 0
+        assert _loop_ops(text, "stablehlo.dynamic_slice") >= 1
+
+    @pytest.mark.parametrize("runner", ["_grid_fn", "_cand_fn"])
+    def test_gather_form_shows_its_gathers(self, runner, monkeypatch):
+        """The check sees a per-lane gather where there is one."""
+        from repro.sim import engine
+
+        monkeypatch.setitem(engine._KERNELS, "event", _gather_event)
+        assert _loop_ops(self._lowered(runner), "stablehlo.gather") >= 1
