@@ -5,7 +5,8 @@ from .params import (CheckpointParams, MultilevelCheckpointParams,
                      EXASCALE_ML_POWER, MU_IND_JAGUAR_MIN,
                      fig12_checkpoint, fig3_checkpoint)
 from .failures import (FailureProcess, Exponential, Weibull, LogNormal,
-                       TraceReplay, get_process, as_process)
+                       TraceReplay, get_process, as_process,
+                       level_loss_flags)
 from .model import (time_final, time_fault_free, time_lost_per_failure,
                     phase_times, energy_final, energy_breakdown,
                     K_factor, K_dE_dT,
@@ -26,5 +27,6 @@ from .tradeoff import (TradeoffPoint, MultilevelTradeoffPoint,
                        RobustnessPoint, evaluate, evaluate_multilevel,
                        evaluate_robustness, sweep_rho, sweep_mu_rho,
                        sweep_nodes, sweep_buddy_ratio)
-from .simulator import simulate, simulate_once, SimResult
+from .simulator import (simulate, simulate_once, SimResult,
+                        simulate_once_ml, MultilevelSimResult)
 from .policy import CheckpointPolicy, PolicyConfig

@@ -516,6 +516,31 @@ def as_process(p) -> FailureProcess:
 
 
 # ---------------------------------------------------------------------------
+# Level-loss flags (two-level checkpointing)
+# ---------------------------------------------------------------------------
+
+#: ``fold_in`` tag that derives the flag stream from a lane's gap key.
+LEVEL_LOSS_STREAM = 1
+
+
+def level_loss_flags(key, size: tuple, q):
+    """Bernoulli(``q``) level-loss flags on device, one per failure.
+
+    The lane whose gaps come from threefry ``key`` draws its flags from a
+    second stream of the same key: flag ``j`` is
+    ``uniform(fold_in(key, LEVEL_LOSS_STREAM), size, float64)[j] < q``
+    (failure ``j`` also loses the buddy copy).  ``q`` may be a traced
+    scalar; ``q = 0`` gives no hard failure and ``q = 1`` only hard ones.
+    A reference replays the stream from the key alone.
+    """
+    import jax
+    import jax.numpy as jnp
+    u = jax.random.uniform(jax.random.fold_in(key, LEVEL_LOSS_STREAM), size,
+                           dtype=jnp.float64)
+    return u < q
+
+
+# ---------------------------------------------------------------------------
 # Scalar helpers (math.gamma / erfc vectorized over small parameter arrays)
 # ---------------------------------------------------------------------------
 

@@ -30,6 +30,10 @@ simulate the tail failure-free (biased); the simulator raises instead,
 mirroring the batched engine's ``gaps_exhausted`` error.  Likewise the event
 budget: exceeding ``max_events`` raises rather than returning a partial
 trajectory.
+
+:func:`simulate_once_ml` is the same phase machine for two-level (buddy +
+PFS) checkpointing, read from a given schedule of ``(gap, hard)`` pairs:
+the plain reference of ``sim.engine.simulate_trajectories_ml``.
 """
 from __future__ import annotations
 
@@ -40,7 +44,8 @@ from typing import Optional, Sequence
 import numpy as np
 
 from .failures import FailureProcess, as_process
-from .params import CheckpointParams, PowerParams
+from .params import (CheckpointParams, MultilevelCheckpointParams,
+                     MultilevelPowerParams, PowerParams)
 
 
 @dataclasses.dataclass
@@ -235,3 +240,154 @@ def simulate(T: float, ckpt: CheckpointParams, power: PowerParams,
         out[k] = m
         out[k + "_se"] = se
     return out
+
+
+@dataclasses.dataclass
+class MultilevelSimResult:
+    wall_time: float
+    energy: float
+    work_executed: float
+    io1_time: float           # buddy level: writes + soft recoveries
+    io2_time: float           # deep level: writes + hard recoveries
+    down_time: float
+    n_failures: int
+    n_hard_failures: int
+    n_ckpt1: int              # committed buddy checkpoints
+    n_ckpt2: int              # committed deep checkpoints
+
+
+def simulate_once_ml(T: float, m: int, ckpt: MultilevelCheckpointParams,
+                     power: MultilevelPowerParams, T_base: float,
+                     gaps: Sequence, hard: Sequence,
+                     max_events: Optional[int] = None) -> MultilevelSimResult:
+    """One two-level trajectory on the schedule ``(gaps[i], hard[i])``.
+
+    The semantics of :class:`~repro.core.params.MultilevelCheckpointParams`
+    as a phase machine, one step per phase segment or failure.  Every
+    period lasts ``T``; period ``k`` of a superperiod (``k = 0..m-1``)
+    computes for ``T - C_k`` and then writes its checkpoint for ``C_k`` at
+    work rate ``omega_k``: the deep level (``C2``, ``omega2``) when
+    ``k == m - 1``, the buddy level (``C1``, ``omega1``) otherwise.  A
+    write commits the work as of its start, at its end; a deep write
+    commits both levels.  Failure ``i`` strikes ``gaps[i]`` after the end
+    of the previous recovery (or t = 0).  A soft one (``hard[i]`` false)
+    costs ``D1 + R1``, rolls back to the last commit of either level and
+    resumes at the period after it; a hard one costs ``D2 + R2``, rolls
+    back to the last deep commit and restarts the superperiod at
+    ``k = 0``.  Recovery time counts as I/O of its level, a write's time
+    (wasted or not) as I/O of the level it writes.
+
+    Raises like :func:`simulate_once` when the schedule or the event
+    budget runs out before ``T_base`` work completes.
+    """
+    m = int(m)
+    C1, R1, D1 = ckpt.C1, ckpt.R1, ckpt.D1
+    C2, R2, D2 = ckpt.C2, ckpt.R2, ckpt.D2
+    w1, w2 = ckpt.w1, ckpt.w2
+    if m < 1:
+        raise ValueError("deep-checkpoint cadence m must be >= 1")
+    if T < max(C1, C2) or T <= ckpt.a(m):
+        raise ValueError("period too short: no work progress per period")
+    gaps = [float(g) for g in np.asarray(gaps, dtype=np.float64).ravel()]
+    hard = [bool(h) for h in np.asarray(hard).ravel()]
+    if len(hard) < len(gaps):
+        raise ValueError("fewer level-loss flags than gaps")
+
+    wall = committed1 = committed2 = live = 0.0
+    work_exec = io1 = io2 = down = 0.0
+    n_fail = n_hard = n_ckpt1 = n_ckpt2 = 0
+    k = 0                             # period index inside the superperiod
+    cost = lambda j: C2 if j == m - 1 else C1
+    drawn = 1
+    exhausted = not gaps
+    next_fail = gaps[0] if gaps else math.inf
+    in_ckpt = False
+    phase_left = T - cost(k)
+    snapshot = 0.0
+
+    if max_events is None:
+        max_events = int(50 * (T_base / (T - ckpt.a(m)) * m
+                               + T_base / ckpt.mu * (m + 2) + 100))
+    for _ in range(max_events):
+        if live >= T_base - 1e-12:
+            break
+        deep = k == m - 1
+        rate = (w2 if deep else w1) if in_ckpt else 1.0
+        t_done = ((T_base - live) / rate) if rate > 0 else math.inf
+        t_next = min(phase_left, t_done)
+
+        if wall + t_next < next_fail:
+            wall += t_next
+            live += rate * t_next
+            work_exec += rate * t_next
+            if in_ckpt:
+                if deep:
+                    io2 += t_next
+                else:
+                    io1 += t_next
+            phase_left -= t_next
+            if live >= T_base - 1e-12:
+                break
+            if phase_left <= 1e-12:
+                if not in_ckpt:
+                    in_ckpt = True
+                    phase_left = cost(k)
+                    snapshot = live
+                else:
+                    committed1 = snapshot
+                    if deep:
+                        committed2 = snapshot
+                        n_ckpt2 += 1
+                    else:
+                        n_ckpt1 += 1
+                    k = (k + 1) % m
+                    in_ckpt = False
+                    phase_left = T - cost(k)
+        else:
+            h = hard[n_fail]
+            dt = next_fail - wall
+            wall = next_fail
+            live += rate * dt
+            work_exec += rate * dt
+            if in_ckpt:
+                if deep:
+                    io2 += dt
+                else:
+                    io1 += dt
+            n_fail += 1
+            n_hard += h
+            wall += D2 if h else D1
+            down += D2 if h else D1
+            wall += R2 if h else R1
+            if h:                     # a soft failure resumes at k
+                io2 += R2
+                committed1 = committed2
+                k = 0
+            else:
+                io1 += R1
+            live = committed1
+            in_ckpt = False
+            phase_left = T - cost(k)
+            if drawn < len(gaps):
+                next_fail = wall + gaps[drawn]
+            else:
+                exhausted = True
+                next_fail = math.inf
+            drawn += 1
+    else:
+        raise RuntimeError(
+            f"simulator exceeded its event budget ({max_events} events) "
+            f"before completing T_base={T_base} work")
+    if exhausted:
+        raise RuntimeError(
+            "failure schedule exhausted before the trajectory completed "
+            "(tail would be simulated failure-free); provide a longer "
+            "schedule")
+
+    energy = (power.P_static * wall + power.P_cal * work_exec
+              + power.P_io1 * io1 + power.P_io2 * io2
+              + power.P_down * down)
+    return MultilevelSimResult(
+        wall_time=wall, energy=energy, work_executed=work_exec,
+        io1_time=io1, io2_time=io2, down_time=down, n_failures=n_fail,
+        n_hard_failures=n_hard, n_ckpt1=n_ckpt1, n_ckpt2=n_ckpt2)

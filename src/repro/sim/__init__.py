@@ -42,7 +42,7 @@ from .engine import (TrajectoryBatch, MultilevelTrajectoryBatch,
                      simulate_candidates, simulate_grid,
                      simulate_trajectories_ml, simulate_grid_ml,
                      presample_gaps, presample_gaps_device,
-                     presample_failures, fail_capacity_points,
+                     fail_capacity_points, fail_capacity_points_ml,
                      step_budget_points)
 from .sweep import (GridResult, MultilevelGridResult, RobustnessResult,
                     evaluate_grid, evaluate_multilevel_grid,

@@ -488,6 +488,22 @@ def run(key, build, args, in_axes: Sequence[Optional[int]], out_axes,
     cache.  Returns host numpy arrays in the output structure, the grid
     axis restored to ``size``.
     """
+    return launch(key, build, args, in_axes, out_axes, size,
+                  per_point_bytes, config, quantum)()
+
+
+def launch(key, build, args, in_axes: Sequence[Optional[int]], out_axes,
+           size: int, per_point_bytes: int = 0,
+           config: Optional[DispatchConfig] = None, quantum: int = 1):
+    """:func:`run` in two halves: launch the first chunk now and return a
+    function that fetches it, runs the remaining chunks one at a time and
+    returns what :func:`run` returns.
+
+    A caller with several independent dispatches launches them all before
+    finishing any, so the device computes the next while the host copies
+    the last one back.  Each pending dispatch holds its first chunk's
+    arguments and outputs on the device until it is finished.
+    """
     cfg = resolve(config)
     ndev = effective_devices(cfg)
     plan = chunk_plan(size, ndev, per_point_bytes, cfg, quantum=quantum)
@@ -502,16 +518,25 @@ def run(key, build, args, in_axes: Sequence[Optional[int]], out_axes,
                        # reprolint: disable=RPL003 (deliberately dtype-preserving: broadcast args arrive as f64 grids, int32 m-candidates, or bool masks, and the chunker must not recast any of them)
                        else jnp.asarray(np.asarray(a)))
                  for a, ax in zip(args, in_axes)]
+
+    def launch_chunk(chunk):
+        start, stop, padded = chunk
+        with jax.profiler.TraceAnnotation("repro.dispatch.launch"), \
+                enable_x64():
+            chunk_args = [
+                const[i] if ax is None
+                else _slice_pad(args[i], ax, start, stop, padded)
+                for i, ax in enumerate(in_axes)]
+            return runner(*chunk_args)
+
+    first = launch_chunk(plan[0])
+
+    def finish():
         treedef = None
         flat_axes = None
         bufs = None
-        for start, stop, padded in plan:
-            with jax.profiler.TraceAnnotation("repro.dispatch.launch"):
-                chunk_args = [
-                    const[i] if ax is None
-                    else _slice_pad(args[i], ax, start, stop, padded)
-                    for i, ax in enumerate(in_axes)]
-                out = runner(*chunk_args)
+        for n, (start, stop, padded) in enumerate(plan):
+            out = first if n == 0 else launch_chunk(plan[n])
             # np.asarray waits for the program to finish, so this span
             # holds its device time as well as the copy-back.
             with jax.profiler.TraceAnnotation("repro.dispatch.fetch"):
@@ -539,4 +564,6 @@ def run(key, build, args, in_axes: Sequence[Optional[int]], out_axes,
                     dst = [slice(None)] * arr.ndim
                     dst[ax] = slice(start, stop)
                     buf[tuple(dst)] = arr[tuple(sel)]
-    return treedef.unflatten(bufs)
+        return treedef.unflatten(bufs)
+
+    return finish

@@ -85,10 +85,10 @@ chunk size, shard count, memory budget, budget bucketing, and
 ``engine_kind`` never change a fixed seed's results
 (tests/test_dispatch.py).  The bulk :func:`presample_gaps_device` sampler
 (single key, whole grid) is kept for direct use and CRN-style workflows.
-The multilevel engine (:func:`simulate_trajectories_ml`) remains a
-single-shot dispatch — its model-grid counterpart
-``sweep.evaluate_multilevel_grid`` IS dispatch-routed, and its runner
-cache is LRU-bounded like the rest.
+The two-level engine (:func:`simulate_trajectories_ml`) takes the same
+path: an event kernel (one step per failure), the fused sampler with a
+second threefry stream for the level-loss flags, buckets and dispatch,
+and its program is ``jit_build_ml`` (docs/simulation.md).
 """
 from __future__ import annotations
 
@@ -106,7 +106,7 @@ from jax import lax
 
 from jax import enable_x64
 
-from ..core.failures import as_process
+from ..core.failures import as_process, level_loss_flags
 from . import dispatch as _dispatch
 from . import precision as _precision
 from .scenarios import MultilevelParamGrid, ParamGrid
@@ -1177,22 +1177,25 @@ def _dispatch_cands(key, build, T2, flat: ParamGrid, Tb_arr, axis: str,
 
 
 # ---------------------------------------------------------------------------
-# Multilevel (buddy + PFS) phase machine
+# Two-level (buddy + PFS) checkpointing: the event kernel
 # ---------------------------------------------------------------------------
 #
 # The superperiod structure: periods 0..m-2 end with a buddy checkpoint
 # (cost C1, commits level 1), period m-1 with a deep checkpoint (cost C2,
-# commits BOTH levels).  Each pre-sampled failure carries a boolean "hard"
-# flag (buddy copy lost, probability q): a soft failure rolls back to the
-# last committed level-1 state and resumes the period schedule where that
-# commit left it; a hard failure rolls back to the last deep commit and
-# restarts the superperiod at period 0 (re-executing the intermediate buddy
-# checkpoints on the way — their I/O is naturally re-counted).
+# commits BOTH levels).  Each failure carries a "hard" flag (buddy copy
+# lost, probability q): a soft failure rolls back to the last committed
+# level-1 state and resumes the period schedule where that commit left it;
+# a hard failure rolls back to the last deep commit and restarts the
+# superperiod at period 0 (re-executing the intermediate buddy checkpoints
+# on the way — their I/O is naturally re-counted).  The plain reference
+# is ``core.simulator.simulate_once_ml``, a phase machine of the same
+# semantics.
 #
 # With m = 1 and degenerate levels (C1=C2, R1=R2, D1=D2) every arithmetic
-# expression below matches the single-level ``_run_one`` operation-for-
-# operation, so the scalar ``simulate_once`` oracle is reproduced
-# bit-for-bit — the parity tests rely on this.
+# expression of the kernel reduces to the single-level ``_run_one_event``
+# operation for operation: the two engines agree bit for bit on a dyadic
+# schedule, where every operation is exact, and to rounding otherwise (XLA
+# may contract multiply-adds differently in two programs).
 
 @dataclasses.dataclass(frozen=True)
 class MultilevelTrajectoryBatch:
@@ -1212,172 +1215,246 @@ class MultilevelTrajectoryBatch:
     gaps_exhausted: np.ndarray
 
 
-def _run_one_ml(T, m, C1, C2, R1, R2, D1, D2, omega1, omega2, T_base,
-                gaps, hard, n_steps):
-    """One two-level trajectory; ``hard[i]`` is the level-loss flag of the
-    i-th failure.  Mirrors ``_run_one`` branch-for-branch.
+def _run_one_ml_event(T, m, C1, C2, R1, R2, D1, D2, omega1, omega2, T_base,
+                      gaps, hard, n_steps):
+    """One two-level trajectory, one scan iteration per FAILURE.
 
-    ``omega1``/``omega2`` are the per-level overlap rates (buddy write /
-    deep flush).  The commit-at-end-of-checkpoint-phase semantics below
-    ARE the hazard-during-flush model: work performed at rate ``omega2``
-    during a deep write belongs to an uncommitted in-flight generation, so
-    a failure inside the flush window rolls back to the previous surviving
-    level and re-executes it.  With ``omega1 == omega2`` the select is
-    value-transparent and the pre-async trajectories are reproduced
-    bit-for-bit."""
+    ``hard[i]`` is the level-loss flag of failure ``i``.  A stretch starts
+    after a recovery (or at t = 0) in period ``k0`` of the superperiod,
+    with ``live == committed1``; from there the machine is deterministic.
+    Every period lasts ``T``; period ``j`` (``j`` counted in the
+    superperiod) writes the deep level if ``j == m - 1`` and the buddy
+    level otherwise, does work ``w_j = T - (1 - omega_j) C_j`` and commits
+    the work as of its checkpoint's start.  So the work of the first ``n``
+    whole periods of the stretch is ``(n - nd) w1 + nd w2``, with
+    ``nd = floor((k0 + n) / m)`` deep ones among them, and:
+
+    * completion: measured from the superperiod's start, ``Q`` whole
+      superperiods (``S = (m-1) w1 + w2`` each) and then ``t <= m - 1``
+      buddy periods fit in ``rem - eps + k0 w1``, so ``n = Q m + t - k0``
+      whole periods come before the finishing one, which is period
+      ``t`` of its superperiod (``eps`` as in ``_run_one_event``);
+    * a failure at ``s = g`` lands in whole period ``kf = floor(s / T)``
+      of the stretch (failure wins ties, as in ``_run_one_event``), which
+      is period ``p = (k0 + kf) mod m``; the last commit of either level
+      is that of period ``kf - 1``, the last deep one that of period
+      ``Q m - 1 - k0`` with ``Q = floor((k0 + kf) / m)`` (none if
+      ``Q == 0``); a soft failure resumes at ``p``, a hard one at 0.
+
+    Step ``i`` reads gap ``i`` and flag ``i`` by loop index, as in
+    ``_run_one_event``: the schedule and the flags reach the scan as
+    ``xs``, padded with ``inf`` and ``False`` (or cut) to ``n_steps``.
+    """
     f64 = gaps.dtype
     n_gaps = gaps.shape[0]
-    C_first = jnp.where(m > 1, C1, C2)      # period 0 is deep only when m=1
+    pad = (0, max(n_steps - n_gaps, 0))
+    slab = jnp.pad(gaps, pad, constant_values=jnp.inf)[:n_steps]
+    flags = jnp.pad(hard, pad, constant_values=False)[:n_steps]
+    in_ranges = jnp.arange(n_steps, dtype=jnp.int32) < n_gaps
+    mf = m.astype(f64)
+    w1 = T - (1.0 - omega1) * C1        # work of a buddy period
+    w2 = T - (1.0 - omega2) * C2        # work of a deep period
+    S = (mf - 1.0) * w1 + w2            # work of a superperiod
 
-    init = (jnp.zeros((), f64),            # wall
-            jnp.zeros((), f64),            # committed1
-            jnp.zeros((), f64),            # committed2
-            jnp.zeros((), f64),            # live
-            jnp.zeros((), f64),            # work_exec
-            jnp.zeros((), f64),            # io1_time
-            jnp.zeros((), f64),            # io2_time
-            jnp.zeros((), f64),            # down_time
-            gaps[0],                       # next_fail
-            T - C_first,                   # phase_left
-            jnp.zeros((), f64),            # snapshot
-            jnp.zeros((), jnp.int32),      # phase = COMPUTE
-            jnp.zeros((), jnp.int32),      # k: period index in superperiod
-            jnp.zeros((), jnp.int32),      # resume_k: soft-rollback restart
-            jnp.zeros((), jnp.int32),      # n_fail
-            jnp.zeros((), jnp.int32),      # n_hard
-            jnp.zeros((), jnp.int32),      # n_ckpt1
-            jnp.zeros((), jnp.int32),      # n_ckpt2
-            jnp.ones((), jnp.int32),       # fail_idx (gaps[0] consumed)
-            jnp.zeros((), jnp.bool_))      # done
+    def period(deep):
+        """(cost, compute length, overlap rate, safe rate) of a period."""
+        C = jnp.where(deep, C2, C1)
+        omega = jnp.where(deep, omega2, omega1)
+        return C, T - C, omega, jnp.where(omega > 0.0, omega, 1.0)
 
-    def step(carry, _):
-        (wall, committed1, committed2, live, work_exec, io1_time, io2_time,
-         down_time, next_fail, phase_left, snapshot, phase, k, resume_k,
-         n_fail, n_hard, n_ckpt1, n_ckpt2, fail_idx, done) = carry
+    def work_of(nb, nd):
+        return nb * w1 + nd * w2
 
-        is_deep = k == m - 1
-        Ck = jnp.where(is_deep, C2, C1)
-        in_ckpt = phase == CHECKPOINT
-        omega_k = jnp.where(is_deep, omega2, omega1)
-        rate = jnp.where(in_ckpt, omega_k, 1.0)
-        t_done = jnp.where(rate > 0.0,
-                           (T_base - live) / jnp.where(rate > 0.0, rate, 1.0),
-                           jnp.inf)
-        t_next = jnp.minimum(phase_left, t_done)
-        no_fail = wall + t_next < next_fail
+    init = (jnp.zeros((), f64),         # wall
+            jnp.zeros((), f64),         # committed1
+            jnp.zeros((), f64),         # committed2
+            jnp.zeros((), f64),         # work_exec
+            jnp.zeros((), f64),         # io1_time
+            jnp.zeros((), f64),         # io2_time
+            jnp.zeros((), f64),         # down_time
+            jnp.zeros((), jnp.int32),   # k: period of the stretch's start
+            jnp.zeros((), jnp.int32),   # n_fail
+            jnp.zeros((), jnp.int32),   # n_hard
+            jnp.zeros((), jnp.int32),   # n_ckpt1
+            jnp.zeros((), jnp.int32),   # n_ckpt2
+            jnp.zeros((), jnp.bool_),   # used_inf (schedule ran dry)
+            jnp.zeros((), jnp.bool_))   # done
 
-        # ---- branch A: the phase segment completes without failure ----
-        wall_a = wall + t_next
-        live_a = live + rate * t_next
-        work_a = work_exec + rate * t_next
-        io1_a = io1_time + jnp.where(in_ckpt & ~is_deep, t_next, 0.0)
-        io2_a = io2_time + jnp.where(in_ckpt & is_deep, t_next, 0.0)
-        left_a = phase_left - t_next
-        finished = live_a >= T_base - _EPS
-        boundary = jnp.logical_and(~finished, left_a <= _EPS)
-        start_ckpt = jnp.logical_and(boundary, ~in_ckpt)
-        end_ckpt = jnp.logical_and(boundary, in_ckpt)
-        phase_a = jnp.where(start_ckpt, CHECKPOINT,
-                            jnp.where(end_ckpt, COMPUTE, phase))
-        k_next = jnp.where(k + 1 >= m, 0, k + 1)
-        C_next = jnp.where(k_next == m - 1, C2, C1)
-        left_a = jnp.where(start_ckpt, Ck,
-                           jnp.where(end_ckpt, T - C_next, left_a))
-        snapshot_a = jnp.where(start_ckpt, live_a, snapshot)
-        committed1_a = jnp.where(end_ckpt, snapshot, committed1)
-        committed2_a = jnp.where(jnp.logical_and(end_ckpt, is_deep),
-                                 snapshot, committed2)
-        k_a = jnp.where(end_ckpt, k_next, k)
-        resume_k_a = jnp.where(end_ckpt, k_next, resume_k)
-        n_ckpt1_a = n_ckpt1 + jnp.logical_and(end_ckpt,
-                                              ~is_deep).astype(jnp.int32)
-        n_ckpt2_a = n_ckpt2 + jnp.logical_and(end_ckpt,
-                                              is_deep).astype(jnp.int32)
+    def step(carry, x):
+        (wall, committed1, committed2, work_exec, io1_time, io2_time,
+         down_time, k, n_fail, n_hard, n_ckpt1, n_ckpt2, used_inf,
+         done) = carry
+        g, h, in_range = x
+        k0 = k.astype(f64)
 
-        # ---- branch B: a failure strikes mid-segment ----
-        hard_f = hard[jnp.minimum(n_fail, n_gaps - 1)]
-        dt = next_fail - wall
-        work_b = work_exec + rate * dt
-        io1_b = io1_time + jnp.where(in_ckpt & ~is_deep, dt, 0.0) \
-            + jnp.where(hard_f, 0.0, R1)
-        io2_b = io2_time + jnp.where(in_ckpt & is_deep, dt, 0.0) \
-            + jnp.where(hard_f, R2, 0.0)
-        D_sel = jnp.where(hard_f, D2, D1)
-        R_sel = jnp.where(hard_f, R2, R1)
-        wall_b = next_fail + D_sel + R_sel
-        down_b = down_time + D_sel
-        gap = jnp.where(fail_idx < n_gaps,
-                        gaps[jnp.minimum(fail_idx, n_gaps - 1)], jnp.inf)
-        next_fail_b = wall_b + gap
-        committed1_b = jnp.where(hard_f, committed2, committed1)
-        k_b = jnp.where(hard_f, 0, resume_k)
-        left_b = T - jnp.where(k_b == m - 1, C2, C1)
+        # ---- closed-form completion from this stretch's start ----
+        rem = T_base - committed1
+        Y = (rem - _EPS) + k0 * w1
+        Q = jnp.maximum(jnp.floor(Y / S), 0.0)
+        t = jnp.clip(jnp.floor((Y - Q * S) / w1), 0.0, mf - 1.0)
+        n = Q * mf + t - k0             # whole periods before the last
+        nb = n - Q
+        r = rem - work_of(nb, Q)        # work inside the finishing period
+        deep_f = t == mf - 1.0
+        _, Tc_f, _, om_f = period(deep_f)
+        rr = r - Tc_f                   # its checkpoint-phase share (if > 0)
+        t_in = jnp.where(rr > 0.0, Tc_f + rr / om_f, r)
+        t_fin = n * T + t_in
+        complete = t_fin < g
+
+        # ---- branch A: completes before the next failure ----
+        wall_a = wall + t_fin
+        work_a = work_exec + rem
+        tail = jnp.maximum(rr, 0.0) / om_f
+        io1_a = io1_time + nb * C1 + jnp.where(deep_f, 0.0, tail)
+        io2_a = io2_time + Q * C2 + jnp.where(deep_f, tail, 0.0)
+
+        # ---- branch B: failure at s = g after the stretch's start ----
+        s = jnp.where(jnp.isfinite(g), g, 0.0)
+        kf = jnp.floor(s / T)
+        # floor(s/T) can land ON kf*T (exact-boundary failure: the
+        # checkpoint ending at the failure instant does NOT commit) or one
+        # above it (quotient rounded up); both correct downward.
+        kf = jnp.where((kf > 0.0) & (kf * T >= s), kf - 1.0, kf)
+        a = k0 + kf                     # the failing period, from k = 0
+        Qb = jnp.floor(a / mf)
+        p = a - Qb * mf
+        deep_b = p == mf - 1.0
+        _, Tc_b, om_b, _ = period(deep_b)
+        u = s - kf * T                  # offset inside the failing period
+        uc = u - Tc_b                   # its checkpoint-phase share (if > 0)
+        nbb = kf - Qb
+        work_b = work_exec + work_of(nbb, Qb) + jnp.where(
+            uc > 0.0, Tc_b + om_b * uc, u)
+        wasted = jnp.maximum(uc, 0.0)
+        io1_b = (io1_time + nbb * C1 + jnp.where(deep_b, 0.0, wasted)
+                 + jnp.where(h, 0.0, R1))
+        io2_b = (io2_time + Qb * C2 + jnp.where(deep_b, wasted, 0.0)
+                 + jnp.where(h, R2, 0.0))
+        D = jnp.where(h, D2, D1)
+        wall_b = (wall + s) + D + jnp.where(h, R2, R1)
+        # Last commit of either level: period kf - 1 of the stretch.
+        Ql = jnp.floor((a - 1.0) / mf)
+        _, Tc_l, _, _ = period(a - 1.0 - Ql * mf == mf - 1.0)
+        last1 = jnp.where(kf >= 1.0, committed1
+                          + work_of(kf - 1.0 - Ql, Ql) + Tc_l, committed1)
+        # Last deep commit: period Qb m - 1 - k0 of the stretch.
+        last2 = jnp.where(Qb >= 1.0, committed1
+                          + work_of(Qb * (mf - 1.0) - k0, Qb - 1.0)
+                          + (T - C2), committed2)
+        committed1_b = jnp.where(h, last2, last1)
+        k_b = jnp.where(h, 0, p.astype(jnp.int32))
 
         def sel(a_val, b_val):
-            return jnp.where(no_fail, a_val, b_val)
+            return jnp.where(complete, a_val, b_val)
 
+        hard_b = n_hard + h.astype(jnp.int32)
         new = (sel(wall_a, wall_b),
-               sel(committed1_a, committed1_b),
-               sel(committed2_a, committed2),
-               sel(live_a, committed1_b),      # rollback to surviving level
+               sel(committed1, committed1_b),
+               sel(committed2, last2),
                sel(work_a, work_b),
                sel(io1_a, io1_b),
                sel(io2_a, io2_b),
-               sel(down_time, down_b),
-               sel(next_fail, next_fail_b),
-               sel(left_a, left_b),
-               sel(snapshot_a, snapshot),
-               sel(phase_a, COMPUTE).astype(jnp.int32),
-               sel(k_a, k_b).astype(jnp.int32),
-               sel(resume_k_a, k_b).astype(jnp.int32),
+               sel(down_time, down_time + D),
+               sel(k, k_b).astype(jnp.int32),
                sel(n_fail, n_fail + 1).astype(jnp.int32),
-               sel(n_hard, n_hard + hard_f.astype(jnp.int32)
-                   ).astype(jnp.int32),
-               sel(n_ckpt1_a, n_ckpt1).astype(jnp.int32),
-               sel(n_ckpt2_a, n_ckpt2).astype(jnp.int32),
-               sel(fail_idx, fail_idx + 1).astype(jnp.int32),
-               jnp.logical_or(done, jnp.logical_and(no_fail, finished)))
+               sel(n_hard, hard_b).astype(jnp.int32),
+               (n_ckpt1 + sel(nb, nbb).astype(jnp.int32)).astype(jnp.int32),
+               (n_ckpt2 + sel(Q, Qb).astype(jnp.int32)).astype(jnp.int32),
+               jnp.logical_or(used_inf, ~in_range),
+               jnp.logical_or(done, complete))
 
         keep = lambda old, upd: jnp.where(done, old, upd)
         return tuple(keep(o, u) for o, u in zip(carry, new)), None
 
-    final, _ = lax.scan(step, init, None, length=n_steps)
-    (wall, _c1, _c2, _live, work_exec, io1_time, io2_time, down_time,
-     _nf, _pl, _snap, _phase, _k, _rk, n_fail, n_hard, n_ckpt1, n_ckpt2,
-     fail_idx, done) = final
+    final, _ = lax.scan(step, init, (slab, flags, in_ranges), length=n_steps)
+    (wall, _c1, _c2, work_exec, io1_time, io2_time, down_time, _k,
+     n_fail, n_hard, n_ckpt1, n_ckpt2, used_inf, done) = final
     return {"wall_time": wall, "work_executed": work_exec,
             "io1_time": io1_time, "io2_time": io2_time,
             "down_time": down_time, "n_failures": n_fail,
             "n_hard_failures": n_hard, "n_ckpt1": n_ckpt1,
             "n_ckpt2": n_ckpt2, "truncated": ~done,
-            "gaps_exhausted": fail_idx > n_gaps}
+            "gaps_exhausted": used_inf}
 
 
-def _make_runner_ml(n_steps: int):
-    def run_grid(T, m, C1, C2, R1, R2, D1, D2, omega1, omega2, T_base,
-                 gaps, hard):
-        def one(t, mm, c1, c2, r1, r2, d1, d2, o1, o2, tb, g, h):
-            return _run_one_ml(t, mm, c1, c2, r1, r2, d1, d2, o1, o2, tb,
-                               g, h, n_steps)
+#: per-point parameters of the two-level kernel, in its argument order.
+_ML_PARAMS = ("C1", "C2", "R1", "R2", "D1", "D2", "omega1", "omega2")
+
+#: the two-level kernel's outputs by dtype, in their packed order.
+_ML_FLOATS = ("wall_time", "work_executed", "io1_time", "io2_time",
+              "down_time")
+_ML_COUNTS = ("n_failures", "n_hard_failures", "n_ckpt1", "n_ckpt2")
+_ML_FLAGS = ("truncated", "gaps_exhausted")
+
+
+def _pack_ml(out: dict) -> tuple:
+    """The kernel's eleven fields as one f64 and one int32 array, fields
+    leading (the grid axis is axis 1), so a chunk comes back to the host
+    in two transfers and not eleven."""
+    return (jnp.stack([out[f] for f in _ML_FLOATS]),
+            jnp.stack([out[f] for f in _ML_COUNTS]
+                      + [out[f].astype(jnp.int32) for f in _ML_FLAGS]))
+
+
+def _unpack_ml(packed) -> dict:
+    """The host side of :func:`_pack_ml`: the fields by name."""
+    floats, ints = packed
+    out = dict(zip(_ML_FLOATS, floats))
+    out.update(zip(_ML_COUNTS, ints))
+    out.update((f, ints[len(_ML_COUNTS) + j] != 0)
+               for j, f in enumerate(_ML_FLAGS))
+    return out
+
+
+def _grid_fn_ml(n_steps: int):
+    """The explicit-schedule (grid x trials) runner of the two-level
+    kernel."""
+    def run_grid_ml(T, m, C1, C2, R1, R2, D1, D2, omega1, omega2, T_base,
+                    gaps, hard):
+        def one(*a):
+            with jax.named_scope(SCAN_SCOPE):
+                return _run_one_ml_event(*a, n_steps)
         over_trials = jax.vmap(one, in_axes=(None,) * 11 + (0, 0))
-        over_grid = jax.vmap(over_trials, in_axes=(0,) * 11 + (0, 0))
-        return over_grid(T, m, C1, C2, R1, R2, D1, D2, omega1, omega2,
-                         T_base, gaps, hard)
-    return jax.jit(run_grid)
+        over_grid = jax.vmap(over_trials, in_axes=(0,) * 13)
+        return _pack_ml(over_grid(T, m, C1, C2, R1, R2, D1, D2, omega1,
+                                  omega2, T_base, gaps, hard))
+    return run_grid_ml
 
 
-#: multilevel runners, LRU-bounded like every other compiled-callable
-#: cache in this module (eviction recompiles, never changes results).
-_ML_RUNNERS = _dispatch.LRUCache(_dispatch.RUNNER_CACHE_SIZE,
-                                 name="engine.ml_runners")
+def _sampled_build_ml(proc_fn, cap_sample: int, cap_used: int,
+                      n_steps: int):
+    """Fused sample-then-simulate chunk kernel of the two-level engine.
 
+    Lane (point ``i``, trial ``t``) owns the folded key ``fold_in(fold_in(
+    key, i), t)``: its gaps are the process's traced draw from that key,
+    as in :func:`_sampled_build`, and its level-loss flags the second
+    stream :func:`repro.core.failures.level_loss_flags` of the same key.
+    Both are drawn at the partition-independent ``cap_sample`` and cut to
+    the bucket's ``cap_used``.  The jitted program is ``jit_build_ml``;
+    it returns its outputs packed by :func:`_pack_ml`.
+    """
+    def build_ml(T, m, C1, C2, R1, R2, D1, D2, omega1, omega2, Tb, q, mean,
+                 idx, t_idx, key, *params):
+        def per_point(t, mm, c1, c2, r1, r2, d1, d2, o1, o2, tb, qq, mu, i,
+                      *pp):
+            with jax.named_scope(SAMPLE_SCOPE):
+                kp = jax.random.fold_in(key, i)
 
-def _runner_ml(n_steps: int):
-    fn = _ML_RUNNERS.get(n_steps)
-    if fn is None:
-        fn = _make_runner_ml(n_steps)
-        _ML_RUNNERS.put(n_steps, fn)
-    return fn
+            def per_trial(ti):
+                with jax.named_scope(SAMPLE_SCOPE):
+                    kl = jax.random.fold_in(kp, ti)
+                    g = proc_fn(kl, (cap_sample,), mu, pp)
+                    h = level_loss_flags(kl, (cap_sample,), qq)
+                with jax.named_scope(SCAN_SCOPE):
+                    return _run_one_ml_event(
+                        t, mm, c1, c2, r1, r2, d1, d2, o1, o2, tb,
+                        g[:cap_used], h[:cap_used], n_steps)
+            return jax.vmap(per_trial)(t_idx)
+        return _pack_ml(jax.vmap(per_point)(
+            T, m, C1, C2, R1, R2, D1, D2, omega1, omega2, Tb, q, mean, idx,
+            *params))
+    return build_ml
 
 
 def _expected_failures_ml(T, m, grid: MultilevelParamGrid,
@@ -1392,35 +1469,17 @@ def _expected_failures_ml(T, m, grid: MultilevelParamGrid,
     return tf / grid.mu
 
 
-def default_fail_capacity_ml(T, m, grid: MultilevelParamGrid, T_base) -> int:
-    """Pre-sampled failures per trajectory: mean + 10 sigma margin."""
-    nf = _expected_failures_ml(T, m, grid, T_base)
-    return int(np.max(np.ceil(nf + 10.0 * np.sqrt(nf + 1.0) + 10.0)))
-
-
-def default_step_budget_ml(T, m, grid: MultilevelParamGrid, T_base) -> int:
-    """Scan length: a hard failure re-executes up to a whole superperiod
-    (m periods, 2 events each), so the per-failure margin scales with m."""
-    work_per_period = np.maximum(T - grid.a(m), 1e-9)
-    periods = T_base / work_per_period
-    nf = _expected_failures_ml(T, m, grid, T_base)
-    per_fail = 2.0 * np.maximum(m * T / work_per_period, 1.0) + 4.0
-    events = 2.0 * periods + 2.0 + nf * per_fail
-    margin = 10.0 * np.sqrt(nf + 1.0) * per_fail
-    return int(np.max(np.ceil(2.0 * events + margin + 64.0)))
-
-
-def presample_failures(grid: MultilevelParamGrid, n_trials: int,
-                       capacity: int, seed: int = 0):
-    """(gaps, hard): exponential(mu) inter-failure gaps and Bernoulli(q)
-    level-loss flags, each of shape ``(B, n_trials, capacity)``."""
-    rng = np.random.default_rng(seed)
-    flat = grid.ravel()
-    mu = flat.mu[:, None, None]
-    gaps = rng.exponential(scale=mu, size=(grid.size, n_trials, capacity))
-    hard = rng.random(size=(grid.size, n_trials, capacity)) \
-        < flat.q[:, None, None]
-    return gaps, hard
+def fail_capacity_points_ml(T, m, grid: MultilevelParamGrid, T_base,
+                            process=None) -> np.ndarray:
+    """Per-grid-point schedule capacity of the two-level engine (mean +
+    10 sigma of the two-level failure count, inflated by the gap CV as in
+    :func:`fail_capacity_points`), bucketed to powers of two; shape
+    ``(grid.size,)``.  The event kernel takes one step per failure, so a
+    point's scan length is its capacity + 1."""
+    cv = np.maximum(1.0, _process_cv_points(process, grid.size))
+    nf = _expected_failures_ml(T, m, grid, T_base) * cv * cv
+    cap = np.ceil(nf + 10.0 * cv * np.sqrt(nf + 1.0) + 10.0)
+    return _pow2(_per_point(cap, grid.size))
 
 
 def _broadcast_schedule(arr, size, dtype):
@@ -1432,65 +1491,10 @@ def _broadcast_schedule(arr, size, dtype):
     return np.broadcast_to(arr, (size, arr.shape[-2], arr.shape[-1]))
 
 
-def simulate_trajectories_ml(T, m, grid: MultilevelParamGrid,
-                             T_base: float = 1.0, n_trials: int = 200,
-                             seed: int = 0,
-                             gaps: Optional[np.ndarray] = None,
-                             hard: Optional[np.ndarray] = None,
-                             n_steps: Optional[int] = None,
-                             ) -> MultilevelTrajectoryBatch:
-    """Simulate every two-level (grid point x trial) trajectory in one
-    jitted call.  ``T`` and ``m`` broadcast against ``grid.shape``; ``gaps``
-    and ``hard`` override the pre-sampled failure schedule (pass the same
-    gaps to the scalar oracle via :class:`ScheduledRNG` for parity checks).
-    """
-    flat = grid.ravel()
-    T_arr = np.broadcast_to(np.asarray(T, dtype=np.float64),
-                            grid.shape).ravel()
-    m_arr = np.broadcast_to(np.asarray(m, dtype=np.int32),
-                            grid.shape).ravel()
-    Tb_arr = np.broadcast_to(np.asarray(T_base, dtype=np.float64),
-                             grid.shape).ravel()
-    if np.any(m_arr < 1):
-        raise ValueError("deep-checkpoint cadence m must be >= 1")
-    if np.any(T_arr < np.maximum(flat.C1, flat.C2)):
-        raise ValueError("period too short: T must cover the checkpoint")
-    if np.any(T_arr <= flat.a(m_arr)):
-        raise ValueError("period too short: no work progress per period")
-
-    if gaps is None or hard is None:
-        cap = default_fail_capacity_ml(T_arr, m_arr, flat, Tb_arr)
-        g, h = presample_failures(flat, n_trials, cap, seed=seed)
-        gaps = g if gaps is None else gaps
-        hard = h if hard is None else hard
-    gaps = _broadcast_schedule(gaps, flat.size, np.float64)
-    hard = _broadcast_schedule(hard, flat.size, np.bool_)
-    if gaps.shape != hard.shape:
-        raise ValueError(f"gaps {gaps.shape} and hard flags {hard.shape} "
-                         f"schedules disagree")
-    n_trials = gaps.shape[-2]
-    if n_steps is None:
-        n_steps = default_step_budget_ml(T_arr, m_arr, flat, Tb_arr)
-    n_steps = 1 << (max(int(n_steps), 1) - 1).bit_length()
-
-    with enable_x64():
-        f64 = jnp.float64
-        out = _runner_ml(int(n_steps))(
-            jnp.asarray(T_arr, dtype=f64),
-            jnp.asarray(m_arr, dtype=jnp.int32),
-            jnp.asarray(flat.C1, dtype=f64),
-            jnp.asarray(flat.C2, dtype=f64),
-            jnp.asarray(flat.R1, dtype=f64),
-            jnp.asarray(flat.R2, dtype=f64),
-            jnp.asarray(flat.D1, dtype=f64),
-            jnp.asarray(flat.D2, dtype=f64),
-            jnp.asarray(flat.omega1, dtype=f64),
-            jnp.asarray(flat.omega2, dtype=f64),
-            jnp.asarray(Tb_arr, dtype=f64),
-            jnp.asarray(gaps, dtype=f64),
-            jnp.asarray(hard, dtype=jnp.bool_))
-        out = {k: np.asarray(v) for k, v in out.items()}
-
+def _assemble_batch_ml(out: dict, grid: MultilevelParamGrid,
+                       n_trials: int) -> MultilevelTrajectoryBatch:
+    """Reshape flat two-level outputs to ``grid.shape + (n_trials,)`` and
+    attach the energy integral."""
     shp = grid.shape + (n_trials,)
     bc = lambda x: x.reshape(grid.shape + (1,))
     wall = out["wall_time"].reshape(shp)
@@ -1512,16 +1516,125 @@ def simulate_trajectories_ml(T, m, grid: MultilevelParamGrid,
         gaps_exhausted=out["gaps_exhausted"].reshape(shp))
 
 
+@functools.partial(jax.profiler.annotate_function,
+                   name="repro.mc.trajectories_ml")
+def simulate_trajectories_ml(T, m, grid: MultilevelParamGrid,
+                             T_base: float = 1.0, n_trials: int = 200,
+                             seed: int = 0,
+                             gaps: Optional[np.ndarray] = None,
+                             hard: Optional[np.ndarray] = None,
+                             n_steps: Optional[int] = None,
+                             process=None,
+                             dispatch=None) -> MultilevelTrajectoryBatch:
+    """Simulate every two-level (grid point x trial) trajectory.
+
+    ``T`` and ``m`` broadcast against ``grid.shape``.  Without a schedule
+    the failures are sampled inside the program, as in
+    :func:`simulate_trajectories`: lane (point ``i``, trial ``t``) draws
+    its gaps from ``process`` (None = exponential at the grid's mu) and
+    its level-loss flags (:func:`repro.core.failures.level_loss_flags`,
+    Bernoulli of the point's ``q``) from the folded key
+    ``fold_in(fold_in(PRNGKey(seed), i), t)``, at the grid-wide capacity.
+    Points are dispatched in power-of-two capacity buckets through
+    :mod:`repro.sim.dispatch` (``dispatch`` is its config), so buckets,
+    chunks, trial blocks and devices never change a fixed seed's results.
+    ``gaps`` and ``hard`` (both, ``(grid.size, n_trials, F)`` or
+    broadcastable) replace the sampled schedule; feed the same schedule to
+    :func:`repro.core.simulator.simulate_once_ml` for parity checks.
+    """
+    flat = grid.ravel()
+    T_arr = np.broadcast_to(np.asarray(T, dtype=np.float64),
+                            grid.shape).ravel()
+    m_arr = np.broadcast_to(np.asarray(m, dtype=np.int32),
+                            grid.shape).ravel()
+    Tb_arr = np.broadcast_to(np.asarray(T_base, dtype=np.float64),
+                             grid.shape).ravel()
+    if np.any(m_arr < 1):
+        raise ValueError("deep-checkpoint cadence m must be >= 1")
+    if np.any(T_arr < np.maximum(flat.C1, flat.C2)):
+        raise ValueError("period too short: T must cover the checkpoint")
+    if np.any(T_arr <= flat.a(m_arr)) or np.any(
+            (m_arr > 1) & (T_arr <= (1.0 - flat.omega1) * flat.C1)):
+        raise ValueError("period too short: no work progress per period")
+    if (gaps is None) != (hard is None):
+        raise ValueError("pass both gaps and hard flags, or neither")
+    cfg = _dispatch.resolve(dispatch)
+    ndev = _dispatch.effective_devices(cfg)
+    per_point = (T_arr, m_arr) + tuple(getattr(flat, f) for f in _ML_PARAMS)
+
+    if gaps is not None:
+        gaps = _broadcast_schedule(gaps, flat.size, np.float64)
+        hard = _broadcast_schedule(hard, flat.size, np.bool_)
+        if gaps.shape != hard.shape:
+            raise ValueError(f"gaps {gaps.shape} and hard flags "
+                             f"{hard.shape} schedules disagree")
+        n_trials = gaps.shape[-2]
+        # One step per failure and one for the completion.
+        ns = (_scan_len(gaps.shape[-1]) + 1 if n_steps is None
+              else _scan_len(n_steps))
+        slots = gaps.shape[-1] + ns
+        tc = _trial_chunk(n_trials, slots, ndev, cfg)
+        acc: dict = {}
+        for t0 in range(0, n_trials, tc):
+            t1 = min(t0 + tc, n_trials)
+            out = _dispatch.run(
+                key=("explicit_ml", ns), build=_grid_fn_ml(ns),
+                args=per_point + (Tb_arr, gaps[:, t0:t1], hard[:, t0:t1]),
+                in_axes=(0,) * 13, out_axes=1, size=flat.size,
+                per_point_bytes=8 * (t1 - t0) * (slots + 32), config=cfg)
+            _scatter(acc, _unpack_ml(out), flat.size, n_trials,
+                     np.arange(flat.size), slice(t0, t1))
+        return _assemble_batch_ml(acc, grid, n_trials)
+
+    caps = fail_capacity_points_ml(T_arr, m_arr, flat, Tb_arr,
+                                   process=process)
+    budgets = (caps + 1 if n_steps is None else
+               np.full(flat.size, _scan_len(n_steps), dtype=np.int64))
+    cap_sample = int(np.max(caps))
+    token, params_b, proc_fn, mean_arr, idx_all, key = _sampler_inputs(
+        as_process(process).ravel(), flat, seed)
+    # Every bucket and trial block is launched before any is fetched, so
+    # the device runs the next while the host copies one back.
+    pending = []
+    for b in np.unique(budgets):
+        idx = np.nonzero(budgets == b)[0]
+        cap = int(np.max(caps[idx]))
+        # f64-sized slots a trajectory holds on device: its gaps and the
+        # flag stream's uniforms at cap_sample, and the scan's two slabs.
+        slots = 2 * cap_sample + 2 * int(b)
+        tc = _trial_chunk(n_trials, slots, ndev, cfg)
+        for t0 in range(0, n_trials, tc):
+            t_idx = np.arange(t0, min(t0 + tc, n_trials), dtype=np.uint32)
+            finish = _dispatch.launch(
+                key=("sampled_ml", token, cap_sample, cap, int(b),
+                     len(params_b)),
+                build=_sampled_build_ml(proc_fn, cap_sample, cap, int(b)),
+                args=tuple(x[idx] for x in per_point)
+                + (Tb_arr[idx], flat.q[idx], mean_arr[idx], idx_all[idx],
+                   t_idx, key) + tuple(p[idx] for p in params_b),
+                in_axes=(0,) * 14 + (None, None) + (0,) * len(params_b),
+                out_axes=1, size=len(idx),
+                per_point_bytes=8 * len(t_idx) * (slots + 32), config=cfg)
+            pending.append((finish, idx, slice(t0, t0 + len(t_idx))))
+    acc = {}
+    for finish, idx, t_slice in pending:
+        _scatter(acc, _unpack_ml(finish()), flat.size, n_trials, idx,
+                 t_slice)
+    return _assemble_batch_ml(acc, grid, n_trials)
+
+
 def simulate_grid_ml(T, m, grid: MultilevelParamGrid, T_base: float = 1.0,
                      n_trials: int = 200, seed: int = 0,
                      gaps: Optional[np.ndarray] = None,
                      hard: Optional[np.ndarray] = None,
-                     n_steps: Optional[int] = None) -> dict:
+                     n_steps: Optional[int] = None, process=None,
+                     dispatch=None) -> dict:
     """Mean/SE summaries of the two-level Monte-Carlo (validates the
     multilevel closed forms; raises on truncation/schedule exhaustion)."""
     tb = simulate_trajectories_ml(T, m, grid, T_base, n_trials=n_trials,
                                   seed=seed, gaps=gaps, hard=hard,
-                                  n_steps=n_steps)
+                                  n_steps=n_steps, process=process,
+                                  dispatch=dispatch)
     if np.any(tb.truncated):
         raise RuntimeError(
             f"{int(tb.truncated.sum())} trajectories exceeded the scan "

@@ -361,7 +361,6 @@ class TestCacheStatsRegistry:
         stats = cache_stats()
         assert "dispatch.runners" in stats
         assert "engine.device_samplers" in stats
-        assert "engine.ml_runners" in stats
         fp = stats["serve.fingerprints"]
         assert fp["hits"] > 0 and fp["inserts"] > 0
         assert fp["lookups"] == fp["hits"] + fp["misses"]

@@ -91,6 +91,34 @@ class TestChunkPlan:
         assert (start, stop) == (0, 7) and padded == 8
 
 
+class TestLaunch:
+    """``launch`` is ``run`` in two halves: several dispatches launched
+    before any is finished give what ``run`` gives, one chunk or many."""
+
+    @staticmethod
+    def _build(x, w, k):
+        return {"y": x * w[:, None] + k, "n": (x > 3.0).astype(np.int32)}
+
+    @pytest.mark.parametrize("chunk", [None, 3])
+    def test_launched_ahead_equals_run(self, chunk):
+        cfg = DispatchConfig(chunk=chunk, shard=False)
+        x = np.arange(10.0).reshape(10, 1) * np.ones((1, 4))
+        calls = [dict(key=("launch-test", k), build=self._build,
+                      args=(x * (k + 1), np.arange(10.0), float(k)),
+                      in_axes=(0, 0, None), out_axes=0, size=10,
+                      config=cfg) for k in range(3)]
+        finishes = [dsp.launch(**c) for c in calls]
+        got = [f() for f in finishes]
+        for c, g in zip(calls, got):
+            want = dsp.run(**c)
+            assert set(g) == {"y", "n"}
+            for k in g:
+                assert g[k].dtype == want[k].dtype
+                np.testing.assert_array_equal(g[k], want[k])
+        np.testing.assert_array_equal(
+            got[2]["y"], 3 * x * np.arange(10.0)[:, None] + 2.0)
+
+
 # ---------------------------------------------------------------------------
 # Chunked == unchunked bit parity (single device)
 # ---------------------------------------------------------------------------

@@ -44,7 +44,19 @@ def _solve():
                                        "energy_mc", "time_penalty_exp")}
 
 
-ENTRIES = {"sweep": _sweep, "solve": _solve}
+ML_GRID = sim.buddy_ratio_grid([0.1], [0.1, 0.5], mu_min=120.0)
+
+
+def _sweep_ml():
+    b = sim.simulate_trajectories_ml(np.array([[16.0, 18.0]]),
+                                     np.array([[8, 4]]), ML_GRID, 600.0,
+                                     n_trials=8, seed=11, process=WEIBULL)
+    return {f: getattr(b, f) for f in ("wall_time", "energy", "n_failures",
+                                       "n_hard_failures", "n_ckpt2",
+                                       "truncated")}
+
+
+ENTRIES = {"sweep": _sweep, "solve": _solve, "sweep_ml": _sweep_ml}
 
 
 def _program_spans(trace_dir) -> list:
@@ -93,11 +105,12 @@ def test_spans_nest(traced):
     assert launch and len(launch) == len(fetch)
     for a, b in zip(launch, fetch):
         assert a[2] <= b[1]
-    if name == "sweep":
-        (outer,) = by("repro.mc.trajectories")
+    if name.startswith("sweep"):
+        entry = {"sweep": "repro.mc.trajectories",
+                 "sweep_ml": "repro.mc.trajectories_ml"}[name]
+        (outer,) = by(entry)
         assert all(_inside(s, outer) for s in launch + fetch)
-        assert {s[0] for s in spans} == {"repro.mc.trajectories",
-                                         "repro.dispatch.launch",
+        assert {s[0] for s in spans} == {entry, "repro.dispatch.launch",
                                          "repro.dispatch.fetch"}
         return
     (solve,) = by("repro.robust.solve")
@@ -153,3 +166,28 @@ def test_scopes_in_lowered_programs(build, kind):
     text = _lowered(build, kind)
     assert engine.SCAN_SCOPE in text
     assert (engine.SAMPLE_SCOPE in text) == build.endswith("sampled")
+
+
+@pytest.mark.parametrize("build", ("sampled", "explicit"))
+def test_scopes_in_lowered_two_level_programs(build):
+    """The two-level programs carry the same scopes: the gap and flag
+    sampler under ``mc.sample``, the two-level scan under ``mc.scan``."""
+    flat = ML_GRID.ravel()
+    B, cap = flat.size, 8
+    per_point = (np.full(B, 16.0), np.full(B, 4, np.int32)) + tuple(
+        getattr(flat, f) for f in engine._ML_PARAMS)
+    Tb = np.full(B, 600.0)
+    if build == "sampled":
+        _, params, fn, mean, idx, key = engine._sampler_inputs(
+            as_process(WEIBULL).ravel(), flat, 3)
+        f = engine._sampled_build_ml(fn, 2 * cap, cap, cap + 1)
+        args = per_point + (Tb, flat.q, mean, idx,
+                            np.arange(4, dtype=np.uint32), key) + params
+    else:
+        f = engine._grid_fn_ml(cap + 1)
+        args = per_point + (Tb, np.full((B, 4, cap), 100.0),
+                            np.zeros((B, 4, cap), bool))
+    with enable_x64():
+        text = jax.jit(f).lower(*args).as_text(debug_info=True)
+    assert engine.SCAN_SCOPE in text
+    assert (engine.SAMPLE_SCOPE in text) == (build == "sampled")
