@@ -273,7 +273,8 @@ def sweep_phase(mus=None, rhos=FIG2_RHOS, n_trials: int = 1024,
     out["capacity_buckets"] = {int(b): int(c)
                                for b, c in zip(buckets, counts)}
     cap_sample = int(caps.max())
-    # The schedule the sampler draws (f64) for one dispatch chunk.
+    # The schedule the top bucket's sampler draws (f64) for one dispatch
+    # chunk; every other bucket draws at its own, smaller capacity.
     per_point = 8 * n_trials * (cap_sample + 32)
     chunk = max(stop - start for start, stop, _ in dsp.chunk_plan(
         grid.size, dsp.effective_devices(DispatchConfig()), per_point))

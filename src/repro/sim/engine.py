@@ -69,13 +69,15 @@ numpy, the CRN solvers' replayable schedules) or — the default
 auto-sampling path — per-(grid point, trial) folded threefry keys fed to
 ``FailureProcess.traced_sampler`` *inside* each dispatch chunk, so the
 ``(B, n_trials, capacity)`` tensor never exists on the host, never pays a
-per-call host->device transfer, and (because every (point, trial) pair
-owns its key and the sampling capacity is the grid-wide max, a
-partition-independent quantity) is bit-identical under every way of
-cutting the work.  Budgets are per-grid-point and bucketed to powers of
-two (:func:`fail_capacity_points` / :func:`step_budget_points`): mixed-mu
-grids are dispatched bucket by bucket so cheap points no longer pay the
-most fragile point's scan length.
+per-call host->device transfer, and is bit-identical under every way of
+cutting the work: every (point, trial) pair owns its key, and each
+bucket draws its lanes' schedules at the bucket's capacity, a prefix of
+the lane's stream (counter-based threefry makes that prefix independent
+of how far the stream is drawn).  Budgets are per-grid-point and
+bucketed to powers of two (:func:`fail_capacity_points` /
+:func:`step_budget_points`): mixed-mu grids are dispatched bucket by
+bucket so cheap points no longer pay the most fragile point's scan
+length or draw.
 
 Every single-level jitted call routes through :mod:`repro.sim.dispatch` —
 multi-device grid-axis sharding over the 1-D sweep mesh, streaming chunks
@@ -742,19 +744,20 @@ def _dispatch_explicit(T_arr, flat: ParamGrid, Tb_arr, gaps, n_steps: int,
             for k in parts[0]}
 
 
-def _sampled_build(proc_fn, cap_sample: int, cap_used: int,
-                   n_steps: int, kind: str, policy=None):
+def _sampled_build(proc_fn, cap_used: int, n_steps: int, kind: str,
+                   policy=None):
     """Fused sample-then-simulate chunk kernel (the auto-sampling path).
 
     Point ``i``/trial ``t`` draws its schedule from the folded key
-    ``fold_in(fold_in(key, i), t)`` at the partition-independent
-    ``cap_sample`` (the grid-wide max capacity) and slices to this
-    bucket's ``cap_used`` — so bucketing, chunking, sharding, and trial
-    blocking are all pure performance knobs for a fixed seed.  The
-    ``(chunk, trials, cap)`` schedule tensor only ever exists inside this
-    jitted call.  The pallas kind samples through the SAME folded keys
-    and then hands the materialized chunk schedule to the blocked
-    kernel, so its draws are bit-identical to the scan kinds'.
+    ``fold_in(fold_in(key, i), t)`` at the bucket's capacity ``cap_used``:
+    a prefix of the lane's stream, the same values whatever length the
+    stream is drawn to (counter-based threefry) — so bucketing, chunking,
+    sharding, and trial blocking are all pure performance knobs for a
+    fixed seed.  The ``(chunk, trials, cap)`` schedule tensor only ever
+    exists inside this jitted call.  The pallas kind samples through the
+    SAME folded keys and then hands the materialized chunk schedule to
+    the blocked kernel, so its draws are bit-identical to the scan
+    kinds'.
     """
     if kind == "pallas":
         run_grid = _grid_fn(n_steps, kind, policy)
@@ -765,7 +768,7 @@ def _sampled_build(proc_fn, cap_sample: int, cap_used: int,
 
                 def sample_trial(ti):
                     return proc_fn(jax.random.fold_in(kp, ti),
-                                   (cap_sample,), m, pp)
+                                   (cap_used,), m, pp)
                 return jax.vmap(sample_trial)(t_idx)
             # One point per loop iteration: the loop writes the f64
             # schedule out before the kernel's cast and relayout.  Fused
@@ -774,7 +777,7 @@ def _sampled_build(proc_fn, cap_sample: int, cap_used: int,
             with jax.named_scope(SAMPLE_SCOPE):
                 gaps = lax.map(lambda a: sample_point(*a),
                                (mean, idx) + tuple(params))
-            return run_grid(T, C, R, D, omega, Tb, gaps[:, :, :cap_used])
+            return run_grid(T, C, R, D, omega, Tb, gaps)
         return build
     kernel = _KERNELS[kind]
 
@@ -785,10 +788,10 @@ def _sampled_build(proc_fn, cap_sample: int, cap_used: int,
 
             def per_trial(ti):
                 with jax.named_scope(SAMPLE_SCOPE):
-                    g = proc_fn(jax.random.fold_in(kp, ti), (cap_sample,),
+                    g = proc_fn(jax.random.fold_in(kp, ti), (cap_used,),
                                 m, pp)
                 with jax.named_scope(SCAN_SCOPE):
-                    return kernel(t, c, r, d, o, tb, g[:cap_used], n_steps)
+                    return kernel(t, c, r, d, o, tb, g, n_steps)
             return jax.vmap(per_trial)(t_idx)
         return jax.vmap(per_point)(T, C, R, D, omega, Tb, mean, idx,
                                    *params)
@@ -886,8 +889,9 @@ def simulate_trajectories(T, grid: ParamGrid, T_base: float = 1.0,
     devices and chunked to a device-memory budget, and the trials axis
     streams in memory-bounded blocks.  Auto-sampled schedules are drawn
     inside each chunk from per-(grid point, trial) folded keys at the
-    grid-wide capacity, so sharding/chunking/budget knobs — like the
-    budget-bucketing knobs above — never change a fixed seed's results.
+    bucket's capacity, a prefix of the lane's stream, so sharding/
+    chunking/budget knobs — like the budget-bucketing knobs above —
+    never change a fixed seed's results.
     """
     engine_kind = resolve_engine_kind(engine_kind)
     flat = grid.ravel()
@@ -919,12 +923,11 @@ def simulate_trajectories(T, grid: ParamGrid, T_base: float = 1.0,
 
     # Auto-sampled path: per-point budgets, one dispatch per pow2 bucket.
     # Point i / trial t samples its schedule from the folded key
-    # fold_in(fold_in(PRNGKey(seed), i), t) at the grid-wide max capacity
-    # (partition-independent), sliced to the bucket's capacity — the
-    # randomness of a fixed seed depends only on (seed, process, capacity
-    # estimate); n_steps, engine_kind, bucket membership, chunk size,
-    # shard count, and memory budget never change the sampled failure
-    # times.
+    # fold_in(fold_in(PRNGKey(seed), i), t) at its bucket's capacity: a
+    # prefix of that key's stream, whose values do not depend on how far
+    # the stream is drawn — so n_steps, engine_kind, bucket membership,
+    # chunk size, shard count, and memory budget never change the sampled
+    # failure times.
     caps = fail_capacity_points(T_arr, flat, Tb_arr, process=process)
     if n_steps is not None:
         budgets = np.full(flat.size, _scan_len(n_steps), dtype=np.int64)
@@ -932,7 +935,6 @@ def simulate_trajectories(T, grid: ParamGrid, T_base: float = 1.0,
         budgets = caps + 1
     else:
         budgets = step_budget_points(T_arr, flat, Tb_arr, process=process)
-    cap_sample = int(np.max(caps))
     proc = as_process(process).ravel()
     try:
         token, params_b, proc_fn, mean_arr, idx_all, key = \
@@ -948,7 +950,8 @@ def simulate_trajectories(T, grid: ParamGrid, T_base: float = 1.0,
         # numpy is the last-resort gate.  Note the bulk tensor is
         # grid-wide, so the memory-bounded-chunking promise only holds
         # for processes with a traced sampler.
-        g_full = _bulk_schedule(flat, n_trials, cap_sample, seed, process)
+        g_full = _bulk_schedule(flat, n_trials, int(np.max(caps)), seed,
+                                process)
 
     ndev = _dispatch.effective_devices(cfg)
     acc: dict = {}
@@ -963,15 +966,15 @@ def simulate_trajectories(T, grid: ParamGrid, T_base: float = 1.0,
                                      int(b), engine_kind, cfg, policy=pol)
             _scatter(acc, out, flat.size, n_trials, idx, slice(None))
             continue
-        slots = cap_sample + _slab_slots(engine_kind, b)
+        slots = cap + _slab_slots(engine_kind, b)
         tc = _trial_chunk(n_trials, slots, ndev, cfg)
         for t0 in range(0, n_trials, tc):
             t_idx = np.arange(t0, min(t0 + tc, n_trials), dtype=np.uint32)
             out = _dispatch.run(
-                key=("sampled", token, cap_sample, cap, int(b),
+                key=("sampled", token, cap, int(b),
                      _kind_token(engine_kind, pol), len(params_b)),
-                build=_sampled_build(proc_fn, cap_sample, cap, int(b),
-                                     engine_kind, policy=pol),
+                build=_sampled_build(proc_fn, cap, int(b), engine_kind,
+                                     policy=pol),
                 args=(T_arr[idx], sub.C, sub.R, sub.D, sub.omega,
                       Tb_arr[idx], mean_arr[idx], idx_all[idx], t_idx,
                       key) + tuple(p[idx] for p in params_b),
@@ -1422,16 +1425,16 @@ def _grid_fn_ml(n_steps: int):
     return run_grid_ml
 
 
-def _sampled_build_ml(proc_fn, cap_sample: int, cap_used: int,
-                      n_steps: int):
+def _sampled_build_ml(proc_fn, cap_used: int, n_steps: int):
     """Fused sample-then-simulate chunk kernel of the two-level engine.
 
     Lane (point ``i``, trial ``t``) owns the folded key ``fold_in(fold_in(
     key, i), t)``: its gaps are the process's traced draw from that key,
     as in :func:`_sampled_build`, and its level-loss flags the second
     stream :func:`repro.core.failures.level_loss_flags` of the same key.
-    Both are drawn at the partition-independent ``cap_sample`` and cut to
-    the bucket's ``cap_used``.  The jitted program is ``jit_build_ml``;
+    Both are drawn at the bucket's capacity ``cap_used``, a prefix of each
+    stream (counter-based threefry: the same values whatever length the
+    stream is drawn to).  The jitted program is ``jit_build_ml``;
     it returns its outputs packed by :func:`_pack_ml`.
     """
     def build_ml(T, m, C1, C2, R1, R2, D1, D2, omega1, omega2, Tb, q, mean,
@@ -1444,12 +1447,12 @@ def _sampled_build_ml(proc_fn, cap_sample: int, cap_used: int,
             def per_trial(ti):
                 with jax.named_scope(SAMPLE_SCOPE):
                     kl = jax.random.fold_in(kp, ti)
-                    g = proc_fn(kl, (cap_sample,), mu, pp)
-                    h = level_loss_flags(kl, (cap_sample,), qq)
+                    g = proc_fn(kl, (cap_used,), mu, pp)
+                    h = level_loss_flags(kl, (cap_used,), qq)
                 with jax.named_scope(SCAN_SCOPE):
                     return _run_one_ml_event(
-                        t, mm, c1, c2, r1, r2, d1, d2, o1, o2, tb,
-                        g[:cap_used], h[:cap_used], n_steps)
+                        t, mm, c1, c2, r1, r2, d1, d2, o1, o2, tb, g, h,
+                        n_steps)
             return jax.vmap(per_trial)(t_idx)
         return _pack_ml(jax.vmap(per_point)(
             T, m, C1, C2, R1, R2, D1, D2, omega1, omega2, Tb, q, mean, idx,
@@ -1534,7 +1537,8 @@ def simulate_trajectories_ml(T, m, grid: MultilevelParamGrid,
     its gaps from ``process`` (None = exponential at the grid's mu) and
     its level-loss flags (:func:`repro.core.failures.level_loss_flags`,
     Bernoulli of the point's ``q``) from the folded key
-    ``fold_in(fold_in(PRNGKey(seed), i), t)``, at the grid-wide capacity.
+    ``fold_in(fold_in(PRNGKey(seed), i), t)``, at its bucket's capacity
+    (a prefix of each stream).
     Points are dispatched in power-of-two capacity buckets through
     :mod:`repro.sim.dispatch` (``dispatch`` is its config), so buckets,
     chunks, trial blocks and devices never change a fixed seed's results.
@@ -1590,7 +1594,6 @@ def simulate_trajectories_ml(T, m, grid: MultilevelParamGrid,
                                    process=process)
     budgets = (caps + 1 if n_steps is None else
                np.full(flat.size, _scan_len(n_steps), dtype=np.int64))
-    cap_sample = int(np.max(caps))
     token, params_b, proc_fn, mean_arr, idx_all, key = _sampler_inputs(
         as_process(process).ravel(), flat, seed)
     # Every bucket and trial block is launched before any is fetched, so
@@ -1600,15 +1603,14 @@ def simulate_trajectories_ml(T, m, grid: MultilevelParamGrid,
         idx = np.nonzero(budgets == b)[0]
         cap = int(np.max(caps[idx]))
         # f64-sized slots a trajectory holds on device: its gaps and the
-        # flag stream's uniforms at cap_sample, and the scan's two slabs.
-        slots = 2 * cap_sample + 2 * int(b)
+        # flag stream's uniforms, and the scan's two slabs.
+        slots = 2 * cap + 2 * int(b)
         tc = _trial_chunk(n_trials, slots, ndev, cfg)
         for t0 in range(0, n_trials, tc):
             t_idx = np.arange(t0, min(t0 + tc, n_trials), dtype=np.uint32)
             finish = _dispatch.launch(
-                key=("sampled_ml", token, cap_sample, cap, int(b),
-                     len(params_b)),
-                build=_sampled_build_ml(proc_fn, cap_sample, cap, int(b)),
+                key=("sampled_ml", token, cap, int(b), len(params_b)),
+                build=_sampled_build_ml(proc_fn, cap, int(b)),
                 args=tuple(x[idx] for x in per_point)
                 + (Tb_arr[idx], flat.q[idx], mean_arr[idx], idx_all[idx],
                    t_idx, key) + tuple(p[idx] for p in params_b),

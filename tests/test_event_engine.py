@@ -577,3 +577,187 @@ class TestSlabLowering:
 
         monkeypatch.setitem(engine._KERNELS, "event", _gather_event)
         assert _loop_ops(self._lowered(runner), "stablehlo.gather") >= 1
+
+
+def _grid_max_build(proc_fn, cap_sample, cap_used, n_steps, kind,
+                    policy=None):
+    """The sampled bucket program drawing every lane's schedule at
+    ``cap_sample`` (the grid-wide maximum capacity) and cutting it to the
+    bucket's ``cap_used``.  The same program as
+    ``engine._sampled_build`` otherwise, expression for expression."""
+    import jax
+    from jax import lax
+    from repro.sim.engine import (_KERNELS, SAMPLE_SCOPE, SCAN_SCOPE,
+                                  _grid_fn)
+
+    if kind == "pallas":
+        run_grid = _grid_fn(n_steps, kind, policy)
+
+        def build(T, C, R, D, omega, Tb, mean, idx, t_idx, key, *params):
+            def sample_point(m, i, *pp):
+                kp = jax.random.fold_in(key, i)
+
+                def sample_trial(ti):
+                    return proc_fn(jax.random.fold_in(kp, ti),
+                                   (cap_sample,), m, pp)
+                return jax.vmap(sample_trial)(t_idx)
+            with jax.named_scope(SAMPLE_SCOPE):
+                gaps = lax.map(lambda a: sample_point(*a),
+                               (mean, idx) + tuple(params))
+            return run_grid(T, C, R, D, omega, Tb, gaps[:, :, :cap_used])
+        return build
+    kernel = _KERNELS[kind]
+
+    def build(T, C, R, D, omega, Tb, mean, idx, t_idx, key, *params):
+        def per_point(t, c, r, d, o, tb, m, i, *pp):
+            with jax.named_scope(SAMPLE_SCOPE):
+                kp = jax.random.fold_in(key, i)
+
+            def per_trial(ti):
+                with jax.named_scope(SAMPLE_SCOPE):
+                    g = proc_fn(jax.random.fold_in(kp, ti), (cap_sample,),
+                                m, pp)
+                with jax.named_scope(SCAN_SCOPE):
+                    return kernel(t, c, r, d, o, tb, g[:cap_used], n_steps)
+            return jax.vmap(per_trial)(t_idx)
+        return jax.vmap(per_point)(T, C, R, D, omega, Tb, mean, idx,
+                                   *params)
+    return build
+
+
+@pytest.fixture
+def grid_max_draw(monkeypatch):
+    """Run a call once through the engine as it is and once with every
+    bucket drawing at ``cap_sample``, on runners compiled for each."""
+    from repro.sim import dispatch, engine
+
+    def both(call, cap_sample):
+        dispatch._RUNNERS.clear()
+        own = call()
+        with monkeypatch.context() as m:
+            m.setattr(engine, "_sampled_build",
+                      lambda fn, cap, n, kind, policy=None: _grid_max_build(
+                          fn, cap_sample, cap, n, kind, policy))
+            dispatch._RUNNERS.clear()
+            grid_max = call()
+        dispatch._RUNNERS.clear()
+        return own, grid_max
+    return both
+
+
+#: dispatch settings of the bucket-draw comparison: one chunk a bucket,
+#: one point a chunk, and a budget that cuts the larger buckets' trials
+#: into blocks.
+_DRAW_DISPATCH = {
+    "default": None,
+    "chunk_1": dict(chunk=1),
+    "trial_blocks": dict(memory_budget_bytes=20_000),
+}
+
+
+class TestBucketDraw:
+    """Each bucket draws its lanes' schedules at its own capacity, a
+    prefix of each lane's stream: the grid-maximum draw cut to the
+    bucket's capacity gives the same bits."""
+
+    @staticmethod
+    def _compare(grid_max_draw, proc, kind, dispatch):
+        from repro.sim import DispatchConfig
+
+        grid = _mixed_mu_grid()
+        caps = fail_capacity_points(np.full(3, 60.0), grid.ravel(),
+                                    np.full(3, 4000.0), process=proc)
+        assert len(np.unique(caps)) == 3
+        cfg = _DRAW_DISPATCH[dispatch]
+        own, grid_max = grid_max_draw(
+            lambda: simulate_trajectories(
+                60.0, grid, T_base=4000.0, n_trials=6, seed=2**40 + 9,
+                process=proc, engine_kind=kind,
+                dispatch=None if cfg is None else DispatchConfig(**cfg)),
+            int(caps.max()))
+        for name, a in _fields(grid_max).items():
+            np.testing.assert_array_equal(getattr(own, name), a,
+                                          err_msg=name)
+        assert own.n_failures.any()
+
+    @pytest.mark.parametrize("dispatch", sorted(_DRAW_DISPATCH))
+    @pytest.mark.parametrize("kind", ["event", "step", "pallas"])
+    def test_bit_identical_to_grid_max_draw(self, grid_max_draw, kind,
+                                            dispatch):
+        self._compare(grid_max_draw, Weibull(shape=0.6), kind, dispatch)
+
+    @pytest.mark.parametrize("proc", PROCESSES, ids=lambda p: p.name)
+    def test_every_process(self, grid_max_draw, proc):
+        self._compare(grid_max_draw, proc, "event", "default")
+
+
+def _tensor_dims(text: str) -> set:
+    """Every dimension of every ranked tensor type in a lowered module."""
+    import re
+
+    return {int(d) for shape in re.findall(r"tensor<((?:\d+x)+)", text)
+            for d in shape.split("x") if d}
+
+
+class TestBucketDrawLowering:
+    """A bucket's program holds no tensor of the grid maximum's length:
+    its random bits and f64 draws are the bucket's own capacity long."""
+
+    CAP, GRID_MAX = 128, 4096
+
+    @staticmethod
+    def _args():
+        from repro.core.failures import as_process
+        from repro.sim import engine
+
+        flat = _mixed_mu_grid().ravel()
+        _, params, fn, mean, idx, key = engine._sampler_inputs(
+            as_process(Weibull(shape=0.7)).ravel(), flat, 3)
+        B = flat.size
+        args = (np.full(B, 60.0), flat.C, flat.R, flat.D, flat.omega,
+                np.full(B, 4000.0), mean, idx, np.arange(2, dtype=np.uint32),
+                key) + params
+        return fn, args
+
+    def _lowered(self, kind: str, grid_max: bool) -> str:
+        import jax
+        from jax import enable_x64
+        from repro.sim import engine
+
+        fn, args = self._args()
+        n = self.CAP + 1
+        f = (_grid_max_build(fn, self.GRID_MAX, self.CAP, n, kind)
+             if grid_max else engine._sampled_build(fn, self.CAP, n, kind))
+        with enable_x64():
+            return jax.jit(f).lower(*args).as_text()
+
+    @pytest.mark.parametrize("kind", ["event", "step", "pallas"])
+    def test_no_grid_max_tensor(self, kind):
+        dims = _tensor_dims(self._lowered(kind, grid_max=False))
+        assert self.GRID_MAX not in dims
+        assert self.CAP in dims
+
+    @pytest.mark.parametrize("kind", ["event", "step", "pallas"])
+    def test_grid_max_draw_shows_its_tensor(self, kind):
+        """The check sees the grid maximum's length where it is drawn."""
+        assert self.GRID_MAX in _tensor_dims(self._lowered(kind,
+                                                           grid_max=True))
+
+    @pytest.mark.parametrize("kind", ["event", "step", "pallas"])
+    def test_every_draw_at_bucket_capacity(self, kind):
+        """Every call of the sampler asks for the bucket's capacity: not a
+        gap short of it, not one past it."""
+        import jax
+        from jax import enable_x64
+        from repro.sim import engine
+
+        fn, args = self._args()
+        sizes = []
+
+        def spy(key, size, mean, params):
+            sizes.append(tuple(size))
+            return fn(key, size, mean, params)
+        with enable_x64():
+            jax.eval_shape(engine._sampled_build(spy, self.CAP,
+                                                 self.CAP + 1, kind), *args)
+        assert sizes and set(sizes) == {(self.CAP,)}

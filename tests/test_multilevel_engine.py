@@ -380,7 +380,7 @@ class TestLowering:
         else:
             _, params, fn, mean, idx, key = engine._sampler_inputs(
                 as_process(Weibull(shape=0.7)).ravel(), flat, 3)
-            f = engine._sampled_build_ml(fn, 2 * cap, cap, cap + 1)
+            f = engine._sampled_build_ml(fn, cap, cap + 1)
             args = per_point + (Tb, flat.q, mean, idx,
                                 np.arange(3, dtype=np.uint32), key) + params
         with enable_x64():
@@ -413,7 +413,7 @@ class TestLowering:
         else:
             _, params, fn, mean, idx, key = engine._sampler_inputs(
                 as_process(Weibull(shape=0.7)).ravel(), flat, 3)
-            f = engine._sampled_build_ml(fn, 2 * cap, cap, cap + 1)
+            f = engine._sampled_build_ml(fn, cap, cap + 1)
             args = per_point + (Tb, flat.q, mean, idx,
                                 np.arange(n, dtype=np.uint32), key) + params
         with enable_x64():
@@ -426,3 +426,119 @@ class TestLowering:
             engine.MultilevelTrajectoryBatch)} - {"energy"}
         assert out["truncated"].dtype == np.bool_
         assert out["n_ckpt2"].dtype == np.int32
+
+
+def _grid_max_build_ml(proc_fn, cap_sample, cap_used, n_steps):
+    """The two-level bucket program drawing every lane's gaps and flags at
+    ``cap_sample`` (the grid-wide maximum capacity) and cutting both to
+    the bucket's ``cap_used``.  The same program as
+    ``engine._sampled_build_ml`` otherwise, expression for expression."""
+    def build_ml(T, m, C1, C2, R1, R2, D1, D2, omega1, omega2, Tb, q, mean,
+                 idx, t_idx, key, *params):
+        def per_point(t, mm, c1, c2, r1, r2, d1, d2, o1, o2, tb, qq, mu, i,
+                      *pp):
+            with jax.named_scope(engine.SAMPLE_SCOPE):
+                kp = jax.random.fold_in(key, i)
+
+            def per_trial(ti):
+                with jax.named_scope(engine.SAMPLE_SCOPE):
+                    kl = jax.random.fold_in(kp, ti)
+                    g = proc_fn(kl, (cap_sample,), mu, pp)
+                    h = level_loss_flags(kl, (cap_sample,), qq)
+                with jax.named_scope(engine.SCAN_SCOPE):
+                    return engine._run_one_ml_event(
+                        t, mm, c1, c2, r1, r2, d1, d2, o1, o2, tb,
+                        g[:cap_used], h[:cap_used], n_steps)
+            return jax.vmap(per_trial)(t_idx)
+        return engine._pack_ml(jax.vmap(per_point)(
+            T, m, C1, C2, R1, R2, D1, D2, omega1, omega2, Tb, q, mean, idx,
+            *params))
+    return build_ml
+
+
+class TestBucketDraw:
+    """Each bucket draws its lanes' gaps and flags at its own capacity, a
+    prefix of each stream: the grid-maximum draw cut to the bucket's
+    capacity gives the same bits."""
+
+    @pytest.mark.parametrize("knob", [
+        dict(),
+        dict(dispatch=DispatchConfig(chunk=1)),
+        dict(dispatch=DispatchConfig(memory_budget_bytes=30_000)),
+    ], ids=["default", "chunk_1", "trial_blocks"])
+    def test_bit_identical_to_grid_max_draw(self, knob, monkeypatch):
+        from repro.sim import dispatch
+
+        caps = engine.fail_capacity_points_ml(
+            _PERIODS[0], _PERIODS[1], _sampled_grid(), 1500.0,
+            process=_KW["process"])
+        assert len(np.unique(caps)) >= 3
+        cap_sample = int(caps.max())
+        dispatch._RUNNERS.clear()
+        own = _sampled(**knob)
+        with monkeypatch.context() as m:
+            m.setattr(engine, "_sampled_build_ml",
+                      lambda fn, cap, n: _grid_max_build_ml(
+                          fn, cap_sample, cap, n))
+            dispatch._RUNNERS.clear()
+            grid_max = _sampled(**knob)
+        dispatch._RUNNERS.clear()
+        for f, a in _fields(grid_max).items():
+            np.testing.assert_array_equal(getattr(own, f), a, err_msg=f)
+        assert own.n_hard_failures.any()
+
+
+class TestBucketDrawLowering:
+    """A two-level bucket's program holds no tensor of the grid maximum's
+    length: its gaps and flag uniforms are the bucket's capacity long."""
+
+    CAP, GRID_MAX = 128, 4096
+
+    @staticmethod
+    def _args():
+        flat = _sampled_grid()
+        B = flat.size
+        _, params, fn, mean, idx, key = engine._sampler_inputs(
+            as_process(Weibull(shape=0.7)).ravel(), flat, 3)
+        per_point = (np.full(B, 20.0), np.full(B, 3, np.int32)) + tuple(
+            getattr(flat, f) for f in engine._ML_PARAMS)
+        return fn, per_point + (np.full(B, 900.0), flat.q, mean, idx,
+                                np.arange(2, dtype=np.uint32), key) + params
+
+    def _lowered(self, grid_max: bool) -> str:
+        from test_event_engine import _tensor_dims
+
+        fn, args = self._args()
+        n = self.CAP + 1
+        f = (_grid_max_build_ml(fn, self.GRID_MAX, self.CAP, n) if grid_max
+             else engine._sampled_build_ml(fn, self.CAP, n))
+        with enable_x64():
+            return _tensor_dims(jax.jit(f).lower(*args).as_text())
+
+    def test_no_grid_max_tensor(self):
+        dims = self._lowered(grid_max=False)
+        assert self.GRID_MAX not in dims
+        assert self.CAP in dims
+
+    def test_grid_max_draw_shows_its_tensor(self):
+        assert self.GRID_MAX in self._lowered(grid_max=True)
+
+    def test_every_draw_at_bucket_capacity(self, monkeypatch):
+        """The gap sampler and the flag stream both ask for the bucket's
+        capacity: not a draw short of it, not one past it."""
+        fn, args = self._args()
+        gaps, flags = [], []
+
+        def spy(key, size, mean, params):
+            gaps.append(tuple(size))
+            return fn(key, size, mean, params)
+
+        def spy_flags(key, size, q):
+            flags.append(tuple(size))
+            return level_loss_flags(key, size, q)
+        monkeypatch.setattr(engine, "level_loss_flags", spy_flags)
+        with enable_x64():
+            jax.eval_shape(engine._sampled_build_ml(spy, self.CAP,
+                                                    self.CAP + 1), *args)
+        assert gaps and set(gaps) == {(self.CAP,)}
+        assert flags and set(flags) == {(self.CAP,)}

@@ -143,7 +143,7 @@ def _lowered(build: str, kind: str) -> str:
             as_process(WEIBULL).ravel(), flat, 3)
         tail = (mean, idx, np.arange(4, dtype=np.uint32), key) + params
         if build == "sampled":
-            f = engine._sampled_build(fn, 2 * cap, cap, n_steps, kind)
+            f = engine._sampled_build(fn, cap, n_steps, kind)
             args = (T,) + grid_args + tail
         else:
             f = engine._cand_sampled_build(fn, cap, n_steps, kind)
@@ -180,7 +180,7 @@ def test_scopes_in_lowered_two_level_programs(build):
     if build == "sampled":
         _, params, fn, mean, idx, key = engine._sampler_inputs(
             as_process(WEIBULL).ravel(), flat, 3)
-        f = engine._sampled_build_ml(fn, 2 * cap, cap, cap + 1)
+        f = engine._sampled_build_ml(fn, cap, cap + 1)
         args = per_point + (Tb, flat.q, mean, idx,
                             np.arange(4, dtype=np.uint32), key) + params
     else:
